@@ -278,9 +278,7 @@ def kernel_microbench() -> None:
     both_modes(
         "amm_gather_1024x128_n256",
         lambda m: lambda: amm_gather(table, idx, n_banks=4, mode=m),
-        "banks=4",
-        get_config("amm_gather", backend, resolve_mode(mode="compiled"),
-                   v=1024, d=128, nb=4, n=256))
+        "banks=4", {})
 
     q = jnp.asarray(rng.standard_normal((4, 8, 64)), jnp.float32)
     k = jnp.asarray(rng.standard_normal((4, 4, 512, 64)), jnp.float32)
@@ -628,8 +626,6 @@ def grad_sync_bench() -> None:
     import textwrap
 
     code = textwrap.dedent("""
-        import os
-        os.environ['XLA_FLAGS'] = '--xla_force_host_platform_device_count=8'
         import jax, jax.numpy as jnp
         from repro.launch.mesh import make_test_mesh
         from repro.launch.roofline import analyze_hlo
@@ -643,15 +639,18 @@ def grad_sync_bench() -> None:
         b1 = analyze_hlo(cmp_.as_text())["collective_bytes"]
         print(f"{b0},{b1},{b1/b0:.3f}")
     """)
-    out = subprocess.run([_sys.executable, "-c", code],
+    # the child only counts HLO bytes on 8 fake host devices: it must
+    # never reach for an accelerator this process may already hold
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=8")
+    out = subprocess.run([_sys.executable, "-c", code], env=env,
                          capture_output=True, text=True, timeout=300)
-    if out.returncode == 0:
-        b0, b1, ratio = out.stdout.strip().splitlines()[-1].split(",")
-        _row("grad_sync.bf16_allreduce_bytes", float(b0), "16M grads")
-        _row("grad_sync.int8_compressed_bytes", float(b1),
-             f"ratio={ratio};error_feedback=repro.runtime.ft")
-    else:
-        _row("grad_sync.error", 0.0, out.stderr[-120:].replace("\n", " "))
+    if out.returncode != 0:
+        raise RuntimeError(f"grad_sync child failed:\n{out.stderr[-2000:]}")
+    b0, b1, ratio = out.stdout.strip().splitlines()[-1].split(",")
+    _row("grad_sync.bf16_allreduce_bytes", float(b0), "16M grads")
+    _row("grad_sync.int8_compressed_bytes", float(b1),
+         f"ratio={ratio};error_feedback=repro.runtime.ft")
 
 
 # ======================================================================
@@ -710,6 +709,8 @@ def main(argv=None) -> None:
                     help="also write rows as machine-readable JSON "
                          "(e.g. BENCH.json) for cross-PR perf tracking")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     only = _only_list(args.only)
     FULL, JOBS, CACHE_DIR = args.full, args.jobs, args.cache_dir
     BACKEND = args.backend

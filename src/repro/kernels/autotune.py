@@ -10,7 +10,7 @@ every call, so callers transparently get tuned configurations; a miss
 falls back to ``DEFAULTS``.
 
 Shape buckets round every dimension up to a power of two: a tuned
-winner for (v=1024, n=256) also serves (v=700, n=200), which keeps the
+winner for (s=1024, b=8) also serves (s=700, b=6), which keeps the
 table small while the candidates themselves are re-legalized against
 the *actual* shape at dispatch time (``ops._pick_block``).
 
@@ -34,7 +34,6 @@ _CACHE_FILE = os.path.join(os.path.dirname(__file__), "_autotune_cache.json")
 _TABLE: dict[str, dict] | None = None
 
 DEFAULTS: dict[str, dict[str, int]] = {
-    "amm_gather": {"block_n": 128},
     "kv_decode": {"block_h": 1},
     "ssd_chunk": {"block_h": 1},
 }
@@ -86,11 +85,6 @@ def _divisors(n: int) -> list[int]:
 
 def candidates(kernel: str, **dims: int) -> list[dict[str, int]]:
     """Legal block configs for one kernel at one (actual) shape."""
-    if kernel == "amm_gather":
-        n = dims["n"]
-        blocks = sorted({b for b in (32, 64, 128, 256, 512, 1024, n)
-                         if b <= n and n % b == 0})
-        return [{"block_n": b} for b in blocks] or [{"block_n": n}]
     if kernel == "kv_decode":
         group = max(dims["hq"] // dims["hkv"], 1)
         return [{"block_h": b} for b in _divisors(group)]
@@ -123,10 +117,6 @@ def _make_call(kernel: str, args: tuple, cfg: dict[str, int], mode: str
                ) -> Callable[[], Any]:
     from repro.kernels import ops
 
-    if kernel == "amm_gather":
-        table, idx, nb = args
-        return lambda: ops.amm_gather(table, idx, n_banks=nb, mode=mode,
-                                      **cfg)
     if kernel == "kv_decode":
         q, k, v, lens, nb = args
         return lambda: ops.kv_decode(q, k, v, lens, n_banks=nb, mode=mode,
@@ -167,10 +157,6 @@ def _standard_problems() -> Iterable[tuple[str, tuple, dict[str, int]]]:
     import jax.numpy as jnp
 
     rng = np.random.default_rng(0)
-    for v, d, nb, n in ((1024, 128, 4, 256), (4096, 64, 8, 2048)):
-        table = jnp.asarray(rng.standard_normal((v, d)), jnp.float32)
-        idx = jnp.asarray(rng.integers(0, v, n), jnp.int32)
-        yield "amm_gather", (table, idx, nb), dict(v=v, d=d, nb=nb, n=n)
     for b, hq, hkv, s, d, nb in ((4, 8, 4, 512, 64, 8),
                                  (8, 16, 2, 1024, 64, 8)):
         q = jnp.asarray(rng.standard_normal((b, hq, d)), jnp.float32)

@@ -1,8 +1,10 @@
 """Public wrappers around the blocked kernels.
 
 Dispatch: every wrapper resolves an execution mode (see
-``lowering.resolve_mode``) and a block configuration (explicit argument
-> autotuned table in ``_autotune_cache.json`` > kernel default).  The
+``lowering.resolve_mode``); ``kv_decode`` and ``ssd_chunk`` also
+resolve a block configuration (explicit argument > autotuned table in
+``_autotune_cache.json`` > kernel default).  ``amm_gather`` serves one
+request per grid step and has no block size to tune.  The
 default mode is *compiled* — real ``pallas_call`` lowering on TPU/GPU,
 the XLA grid path on CPU — and runs through a jit'd implementation
 with mode and blocks held static.
@@ -59,29 +61,22 @@ def pack_amm_banks(table: jax.Array, n_banks: int
     return banks, parity
 
 
-def _amm_gather_impl(table, idx, n_banks, mode, block_n):
+def _amm_gather_impl(table, idx, n_banks, mode):
     banks, parity = pack_amm_banks(table, n_banks)
-    out = amm_gather_u32(banks, parity, idx.astype(jnp.int32),
-                         block_n=block_n, mode=mode)
+    out = amm_gather_u32(banks, parity, idx.astype(jnp.int32), mode=mode)
     return jax.lax.bitcast_convert_type(out, table.dtype)
 
 
-_amm_gather = jax.jit(_amm_gather_impl,
-                      static_argnames=("n_banks", "mode", "block_n"))
+_amm_gather = jax.jit(_amm_gather_impl, static_argnames=("n_banks", "mode"))
 
 
 def amm_gather(table: jax.Array, idx: jax.Array, n_banks: int = 4,
-               interpret: bool | None = None, mode: str | None = None,
-               block_n: int | None = None) -> jax.Array:
+               interpret: bool | None = None, mode: str | None = None
+               ) -> jax.Array:
     """Conflict-free XOR-banked gather.  table: [V, D]; idx: [N]."""
     mode = resolve_mode(interpret, mode)
-    v, d = table.shape
-    n = int(idx.shape[0])
-    if block_n is None:
-        block_n = _config("amm_gather", mode, v=v, d=d, nb=n_banks,
-                          n=n)["block_n"]
     fn = _amm_gather_impl if mode == "interpret" else _amm_gather
-    return fn(table, idx, n_banks, mode, _pick_block(block_n, n))
+    return fn(table, idx, n_banks, mode)
 
 
 def _kv_decode_impl(q, k, v, lengths, n_banks, mode, block_h):
@@ -111,8 +106,11 @@ def kv_decode(q: jax.Array, k: jax.Array, v: jax.Array, lengths: jax.Array,
     if block_h is None:
         block_h = _config("kv_decode", mode, b=b, hq=hq, hkv=hkv, s=s,
                           d=d, nb=n_banks)["block_h"]
+    block_h = _pick_block(block_h, group)
+    if mode == "pallas" and block_h % 8:
+        block_h = group   # Mosaic: the query block is 8-row aligned or whole
     fn = _kv_decode_impl if mode == "interpret" else _kv_decode
-    return fn(q, k, v, lengths, n_banks, mode, _pick_block(block_h, group))
+    return fn(q, k, v, lengths, n_banks, mode, block_h)
 
 
 def _ssd_chunk_impl(x, dt, cum, B, C, h_in, mode, block_h):

@@ -1,5 +1,7 @@
-"""Serving driver: prefill a batch of prompts, then decode with the
-banked KV cache (example application for the inference shapes).
+"""Serving entry point: prefill a batch of prompts, then decode greedily from
+the model's dense KV cache (example application for the inference
+shapes).  The decode attention is the plain jnp path in
+``models/attention.py``; the banked Pallas decode kernel is not on it.
 
   PYTHONPATH=src python -m repro.launch.serve --arch qwen3-1.7b \
       --preset tiny --batch 4 --prompt-len 64 --gen 32
@@ -15,6 +17,7 @@ import numpy as np
 
 from repro.configs import ARCH_NAMES, get_arch, tiny_variant
 from repro.configs.base import RuntimeConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.steps import make_decode_step, make_prefill_step
 from repro.memory import plan_memory
 from repro.configs.base import SHAPES
@@ -30,6 +33,7 @@ def main(argv=None) -> dict:
     ap.add_argument("--gen", type=int, default=32)
     ap.add_argument("--mla-absorb", action="store_true")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     arch = get_arch(args.arch)
     if args.preset == "tiny":
@@ -62,6 +66,7 @@ def main(argv=None) -> dict:
         if arch.is_encdec:
             print("enc-dec: decoding against zero cross-cache (driver demo)")
         last = tokens[:, :1]
+        logits = None
     else:
         prefill_step = jax.jit(make_prefill_step(arch, rt, policy, cache_len))
         t0 = time.time()
@@ -69,6 +74,7 @@ def main(argv=None) -> dict:
         t_prefill = time.time() - t0
         print(f"prefill {args.batch}x{args.prompt_len}: {t_prefill:.3f}s")
         last = jnp.argmax(logits[:, -1:, :], axis=-1).astype(jnp.int32)
+    prefill_logits = logits
 
     decode = jax.jit(make_decode_step(arch, rt, policy))
     outs = []
@@ -82,7 +88,8 @@ def main(argv=None) -> dict:
     print(f"decode: {toks} tokens in {dt:.3f}s -> {toks/dt:.1f} tok/s")
     gen = np.concatenate(outs, axis=1)
     print("sample continuation ids:", gen[0, :16].tolist())
-    return {"tok_per_s": toks / dt, "generated": gen}
+    return {"tok_per_s": toks / dt, "generated": gen, "prompt": tokens,
+            "prefill_logits": prefill_logits, "decode_logits": logits}
 
 
 if __name__ == "__main__":

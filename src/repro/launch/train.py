@@ -23,6 +23,7 @@ from repro.checkpoint import CheckpointManager
 from repro.configs import ARCH_NAMES, get_arch, tiny_variant
 from repro.configs.base import ArchConfig, RuntimeConfig
 from repro.data import DataConfig, PrefetchLoader, SyntheticCorpus
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.steps import make_train_step
 from repro.models import DTypePolicy, count_params, init_model
 from repro.optim import adamw
@@ -61,6 +62,7 @@ def main(argv=None) -> dict:
                     help="crash (and auto-restart once) at this step")
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     arch = build_arch(args)
     rt = RuntimeConfig(accum_steps=args.accum, remat="none")

@@ -21,7 +21,6 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
@@ -53,9 +52,9 @@ def compressed_pod_mean(grads: Any, mesh: Mesh, axis: str = "pod") -> Any:
         # in reality the grads VARY across pods (per-pod local grads) but
         # are replicated within a pod; P() can't express that, so the
         # static replication check is disabled.
-        return shard_map(
+        return jax.shard_map(
             inner, mesh=mesh,
-            in_specs=P(), out_specs=P(), check_rep=False,
+            in_specs=P(), out_specs=P(), check_vma=False,
         )(g)
 
     return jax.tree.map(sync_leaf, grads)
@@ -70,7 +69,7 @@ def uncompressed_pod_mean(grads: Any, mesh: Mesh, axis: str = "pod") -> Any:
             return (jax.lax.psum(gl.astype(jnp.bfloat16), axis)
                     / n).astype(gl.dtype)
 
-        return shard_map(inner, mesh=mesh, in_specs=P(), out_specs=P(),
-                         check_rep=False)(g)
+        return jax.shard_map(inner, mesh=mesh, in_specs=P(), out_specs=P(),
+                             check_vma=False)(g)
 
     return jax.tree.map(sync_leaf, grads)

@@ -18,19 +18,8 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
-
-
-def _pvary(x: jax.Array, axes: tuple) -> jax.Array:
-    """Mark ``x`` device-varying along ``axes`` (jax >= 0.6 ``lax.pvary``).
-
-    Older jax has no varying-axis type system inside ``shard_map``;
-    there the marker is semantically the identity, so fall back to it.
-    """
-    fn = getattr(jax.lax, "pvary", None)
-    return fn(x, axes) if fn is not None else x
 
 
 def split_stages(stacked_params: Any, n_stages: int) -> Any:
@@ -72,9 +61,11 @@ def pipeline_apply(
         params_local = jax.tree.map(lambda x: x[0], staged_local)
         mb_shape = mbs.shape[1:]
         # carriers must be marked device-varying along the pipe axis
-        h = _pvary(jnp.zeros(mb_shape, mbs.dtype), (axis,))
-        outs = _pvary(jnp.zeros((m,) + mb_shape, mbs.dtype), (axis,))
-        mbs = _pvary(mbs, (axis,))
+        h = jax.lax.pcast(jnp.zeros(mb_shape, mbs.dtype), (axis,),
+                          to="varying")
+        outs = jax.lax.pcast(jnp.zeros((m,) + mb_shape, mbs.dtype), (axis,),
+                             to="varying")
+        mbs = jax.lax.pcast(mbs, (axis,), to="varying")
 
         def tick(carry, t):
             h, outs = carry
@@ -105,7 +96,7 @@ def pipeline_apply(
         return outs
 
     pspec_params = jax.tree.map(lambda _: P(axis), staged_params)
-    fn = shard_map(
+    fn = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(pspec_params, P()),
         out_specs=P(),

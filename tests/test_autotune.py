@@ -15,8 +15,6 @@ RNG = np.random.default_rng(5)
 
 
 def test_candidates_are_legal():
-    for cfg in autotune.candidates("amm_gather", v=1024, d=64, nb=4, n=384):
-        assert 384 % cfg["block_n"] == 0
     for cfg in autotune.candidates("kv_decode", b=2, hq=8, hkv=2, s=64,
                                    d=16, nb=4):
         assert (8 // 2) % cfg["block_h"] == 0
@@ -27,9 +25,9 @@ def test_candidates_are_legal():
 
 
 def test_shape_key_pow2_bucketing():
-    k1 = autotune.shape_key("amm_gather", "cpu", "xla", v=1000, n=200)
-    k2 = autotune.shape_key("amm_gather", "cpu", "xla", v=1024, n=256)
-    k3 = autotune.shape_key("amm_gather", "cpu", "xla", v=1025, n=256)
+    k1 = autotune.shape_key("kv_decode", "cpu", "xla", s=1000, b=200)
+    k2 = autotune.shape_key("kv_decode", "cpu", "xla", s=1024, b=256)
+    k3 = autotune.shape_key("kv_decode", "cpu", "xla", s=1025, b=256)
     assert k1 == k2 != k3
 
 
@@ -44,18 +42,18 @@ def test_pick_block_relegalizes():
 def test_table_roundtrip_and_fallback(tmp_path):
     path = tmp_path / "cache.json"
     entries = {
-        autotune.shape_key("amm_gather", "cpu", "xla", v=64, n=32):
-            {"config": {"block_n": 16}, "us": 1.0},
+        autotune.shape_key("ssd_chunk", "cpu", "xla", h=64, q=32):
+            {"config": {"block_h": 16}, "us": 1.0},
     }
     autotune.save_table(entries, str(path))
     loaded = autotune.load_table(str(path), refresh=True)
     assert loaded == json.loads(path.read_text())["entries"]
     try:
-        got = autotune.get_config("amm_gather", "cpu", "xla", v=64, n=32)
-        assert got == {"block_n": 16}
+        got = autotune.get_config("ssd_chunk", "cpu", "xla", h=64, q=32)
+        assert got == {"block_h": 16}
         # miss -> kernel default
-        miss = autotune.get_config("amm_gather", "cpu", "xla", v=8192, n=8192)
-        assert miss == autotune.DEFAULTS["amm_gather"]
+        miss = autotune.get_config("ssd_chunk", "cpu", "xla", h=8192, q=8192)
+        assert miss == autotune.DEFAULTS["ssd_chunk"]
     finally:
         autotune.load_table(refresh=True)      # restore the real table
 
@@ -70,22 +68,24 @@ def test_corrupt_table_reads_as_empty(tmp_path):
 
 
 def test_tune_end_to_end_records_winner():
-    table = jnp.asarray(RNG.standard_normal((64, 8)), jnp.float32)
-    idx = jnp.asarray(RNG.integers(0, 64, 32), jnp.int32)
+    q = jnp.asarray(RNG.standard_normal((2, 8, 16)), jnp.float32)
+    k = jnp.asarray(RNG.standard_normal((2, 2, 64, 16)), jnp.float32)
+    lens = jnp.asarray([64, 9], jnp.int32)
     entries = {}
-    entry = autotune.tune("amm_gather", (table, idx, 2),
-                          dict(v=64, d=8, nb=2, n=32), repeat=2,
+    entry = autotune.tune("kv_decode", (q, k, k, lens, 4),
+                          dict(b=2, hq=8, hkv=2, s=64, d=16, nb=4), repeat=2,
                           entries=entries)
     assert entry["config"] in [r["config"] for r in entry["swept"]]
     assert entry["us"] == min(r["us"] for r in entry["swept"])
     assert len(entries) == 1
     key = next(iter(entries))
-    assert key.startswith(f"amm_gather|{jax.default_backend()}|")
+    assert key.startswith(f"kv_decode|{jax.default_backend()}|")
 
 
 def test_tuned_config_changes_nothing_numerically():
     """Whatever block the table picks, results must equal the oracle —
-    dispatch through the real checked-in table."""
+    dispatch through the real checked-in table (amm_gather has no
+    block to pick and must still match)."""
     table = jnp.asarray(RNG.standard_normal((1024, 128)), jnp.float32)
     idx = jnp.asarray(RNG.integers(0, 1024, 256), jnp.int32)
     got = amm_gather(table, idx, n_banks=4)        # tuned dispatch

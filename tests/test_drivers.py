@@ -1,9 +1,30 @@
 """End-to-end driver tests: training loop (loss decreases, checkpoint
 recovery works), serving loop (tokens come out), gradient compression
-path."""
+path, the compile-cache placement the entry points share, and
+chip_smoke.py's refusal to run off a TPU."""
 import os
+import shutil
+import subprocess
+import sys
+from pathlib import Path
 
 import pytest
+
+ROOT = Path(__file__).resolve().parents[1]
+
+
+@pytest.fixture(autouse=True)
+def _compile_cache_in_tmp(tmp_path, monkeypatch):
+    """The entry points turn on JAX's persistent compilation cache; keep
+    it in this test's tmp dir and turn it back off afterwards."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "jc"))
+    was = jax.config.jax_compilation_cache_dir
+    yield
+    jax.config.update("jax_compilation_cache_dir", was)
+    compilation_cache.reset_cache()
 
 
 def test_train_driver_tiny(tmp_path):
@@ -56,3 +77,71 @@ def test_serve_driver_ssm():
     out = main(["--arch", "mamba2-130m", "--preset", "tiny", "--batch", "2",
                 "--prompt-len", "16", "--gen", "4"])
     assert out["generated"].shape == (2, 4)
+
+
+def test_compile_cache_dir_env_then_checkout(monkeypatch, tmp_path):
+    from repro.launch.compile_cache import compile_cache_dir
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert compile_cache_dir() == str(tmp_path)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    assert compile_cache_dir() == str(ROOT / ".jax_cache")
+
+
+def test_enable_compile_cache_points_jax_at_it(tmp_path):
+    import jax
+
+    from repro.launch.compile_cache import enable_compile_cache
+
+    assert enable_compile_cache() == str(tmp_path / "jc")
+    assert jax.config.jax_compilation_cache_dir == str(tmp_path / "jc")
+
+
+def _smoke(script: Path) -> subprocess.CompletedProcess:
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    return subprocess.run([sys.executable, str(script)], env=env,
+                          capture_output=True, text=True, timeout=300)
+
+
+def test_chip_smoke_refuses_cpu():
+    out = _smoke(ROOT / "chip_smoke.py")
+    assert out.returncode != 0
+    assert "needs a TPU" in out.stderr
+    assert '"ok"' not in out.stdout
+
+
+def test_chip_smoke_alone_refuses(tmp_path):
+    shutil.copy(ROOT / "chip_smoke.py", tmp_path)
+    out = _smoke(tmp_path / "chip_smoke.py")
+    assert out.returncode != 0
+    assert "no src/repro" in out.stderr
+    assert '"ok"' not in out.stdout
+
+
+def test_chip_smoke_phases_at_tiny_size():
+    """The smoke's phases end to end on the CPU at tiny sizes: the same
+    checks, with kernels on the XLA path instead of Mosaic."""
+    sys.path.insert(0, str(ROOT))
+    import chip_smoke
+
+    chip_smoke.phase_serve("tiny", batch=2, prompt_len=16, gen=4)
+    chip_smoke.phase_explorer(["radix_sort"])
+    chip_smoke.phase_kernels(
+        dict(b=2, hq=4, hkv=2, d=128, s=256, n_banks=4),
+        dict(bt=1, h=4, q=64, p=32, n=16),
+        dict(v=1024, d=256, n_banks=4, n=33), require_mosaic=False)
+
+
+def test_chip_smoke_sharded_phase_on_four_virtual_devices():
+    """``--chips 4``'s path at tiny size on 4 virtual CPU devices: the
+    2x2 mesh, the placement check and the one-device comparison."""
+    code = ("import chip_smoke; "
+            "chip_smoke.phase_sharded('tiny', batch=8, prompt_len=16, "
+            "steps=3)")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=os.pathsep.join([str(ROOT), str(ROOT / "src")]))
+    out = subprocess.run([sys.executable, "-c", code], env=env, cwd=ROOT,
+                         capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert "[sharded.serve]" in out.stdout

@@ -29,18 +29,17 @@ MODES = ("interpret", "xla")
 
 # ----------------------------------------------------------------- amm
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("v,d,nb,n,bn", [
-    (64, 8, 2, 16, 8), (128, 16, 4, 64, 32), (256, 32, 8, 128, 128),
-    (96, 8, 3, 48, 16),          # odd bank count
-    (250, 8, 5, 50, 25),         # non-pow2 table depth and banks
-    (64, 8, 1, 32, 32),          # single-bank degenerate geometry
+@pytest.mark.parametrize("v,d,nb,n", [
+    (64, 8, 2, 16), (128, 16, 4, 64), (256, 32, 8, 128),
+    (96, 8, 3, 48),              # odd bank count
+    (250, 8, 5, 49),             # non-pow2 depth and banks, odd request count
+    (64, 8, 1, 32),              # single-bank degenerate geometry
 ])
-def test_amm_gather_parity(dtype, v, d, nb, n, bn):
+def test_amm_gather_parity(dtype, v, d, nb, n):
     table = jnp.asarray(RNG.standard_normal((v, d)), dtype)
     idx = jnp.asarray(RNG.integers(0, v, n), jnp.int32)
     want = ref.amm_gather_ref(table, idx)
-    outs = {m: amm_gather(table, idx, n_banks=nb, mode=m, block_n=bn)
-            for m in MODES}
+    outs = {m: amm_gather(table, idx, n_banks=nb, mode=m) for m in MODES}
     for m, got in outs.items():
         assert jnp.array_equal(got, want), f"{m} != oracle"
     assert jnp.array_equal(outs["interpret"], outs["xla"])
@@ -58,8 +57,8 @@ def test_amm_gather_replay_oracle_parity():
 
 
 def test_amm_gather_block_autoselect():
-    """Any request count runs: the dispatcher re-legalizes the tuned
-    block size against the actual shape (incl. primes)."""
+    """Any request count runs (incl. primes): one request per grid step,
+    so no block size has to divide the request count."""
     table = jnp.asarray(RNG.standard_normal((64, 8)), jnp.float32)
     for n in (1, 7, 63, 97, 128):
         idx = jnp.asarray(RNG.integers(0, 64, n), jnp.int32)
@@ -178,6 +177,18 @@ def test_env_override_is_default_only(monkeypatch):
     assert resolve_mode(None, None) == "interpret"
     assert resolve_mode(False, None) == "interpret"
     assert resolve_mode(None, "xla") == "xla"   # explicit mode still wins
+
+
+def test_env_override_refused_on_tpu(monkeypatch):
+    """On a TPU the override may not move default calls off Mosaic; an
+    explicit ``mode=`` is still honoured, and the CPU keeps the override."""
+    monkeypatch.setenv("REPRO_KERNEL_MODE", "interpret")
+    with pytest.raises(ValueError, match="REPRO_KERNEL_MODE"):
+        resolve_mode(None, None, backend="tpu")
+    assert resolve_mode(None, "xla", backend="tpu") == "xla"
+    assert resolve_mode(None, None, backend="cpu") == "interpret"
+    monkeypatch.setenv("REPRO_KERNEL_MODE", "pallas")
+    assert resolve_mode(None, None, backend="tpu") == "pallas"
 
 
 def test_compiled_executes_with_interpret_false():
