@@ -1,0 +1,11 @@
+"""decode_step_ms: device time of one run of the decode step program
+(``launch/steps.py:make_decode_step`` under ``jax.jit``), mean over the
+runs in the traced window, in ms."""
+from bench.metrics._program import program_seconds
+
+PROGRAM = "jit_serve_step"
+
+
+def read(run: dict) -> float | None:
+    found = program_seconds(run, PROGRAM)
+    return None if found is None else 1e3 * found[0] / found[1]
