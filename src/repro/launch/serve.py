@@ -21,7 +21,7 @@ from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.steps import make_decode_step, make_prefill_step
 from repro.memory import plan_memory
 from repro.configs.base import SHAPES
-from repro.models import DTypePolicy, init_model
+from repro.models import DTypePolicy, init_model, make_cache
 
 
 def main(argv=None) -> dict:
@@ -59,31 +59,46 @@ def main(argv=None) -> dict:
             rng.standard_normal((args.batch, arch.n_patches, arch.vit_dim)),
             jnp.float32)
 
-    if arch.family in ("hybrid",) or arch.is_encdec:
+    from_empty = arch.family in ("hybrid",) or arch.is_encdec
+    decode = jax.jit(make_decode_step(arch, rt, policy))
+    # compile both steps before any timer starts, so the times below are
+    # of the steps alone
+    t0 = time.perf_counter()
+    if from_empty:
+        cache_spec = jax.eval_shape(
+            lambda: make_cache(arch, cache_len, args.batch, policy))
+    else:
+        prefill_jit = jax.jit(make_prefill_step(arch, rt, policy, cache_len))
+        prefill_step = prefill_jit.lower(params, batch).compile()
+        cache_spec = jax.eval_shape(prefill_jit, params, batch)[1]
+    decode_step = decode.lower(
+        params, cache_spec,
+        jax.ShapeDtypeStruct((args.batch, 1), jnp.int32)).compile()
+    print(f"compile (prefill and decode steps): "
+          f"{time.perf_counter() - t0:.3f}s")
+
+    if from_empty:
         # drivers for these families decode from an empty cache
-        from repro.models import make_cache
         cache = make_cache(arch, cache_len, args.batch, policy)
         if arch.is_encdec:
             print("enc-dec: decoding against zero cross-cache (driver demo)")
         last = tokens[:, :1]
         logits = None
     else:
-        prefill_step = jax.jit(make_prefill_step(arch, rt, policy, cache_len))
-        t0 = time.time()
+        t0 = time.perf_counter()
         logits, cache = jax.block_until_ready(prefill_step(params, batch))
-        t_prefill = time.time() - t0
+        t_prefill = time.perf_counter() - t0
         print(f"prefill {args.batch}x{args.prompt_len}: {t_prefill:.3f}s")
         last = jnp.argmax(logits[:, -1:, :], axis=-1).astype(jnp.int32)
     prefill_logits = logits
 
-    decode = jax.jit(make_decode_step(arch, rt, policy))
     outs = []
-    t0 = time.time()
+    t0 = time.perf_counter()
     for i in range(args.gen):
-        last, logits, cache = decode(params, cache, last)
+        last, logits, cache = decode_step(params, cache, last)
         outs.append(np.asarray(last))
     jax.block_until_ready(logits)
-    dt = time.time() - t0
+    dt = time.perf_counter() - t0
     toks = args.gen * args.batch
     print(f"decode: {toks} tokens in {dt:.3f}s -> {toks/dt:.1f} tok/s")
     gen = np.concatenate(outs, axis=1)

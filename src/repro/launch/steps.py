@@ -73,7 +73,8 @@ def make_decode_step(arch: ArchConfig, rt: RuntimeConfig,
                      policy: DTypePolicy) -> Callable:
     def serve_step(params, cache, tokens):
         logits, cache = decode_step(params, arch, cache, tokens, rt, policy)
-        next_tok = jnp.argmax(logits[:, -1, :], axis=-1)[:, None]
+        with jax.named_scope("head"):
+            next_tok = jnp.argmax(logits[:, -1, :], axis=-1)[:, None]
         return next_tok.astype(jnp.int32), logits, cache
 
     return serve_step

@@ -152,6 +152,7 @@ def _replicate_kv(cfg: AttnConfig, k: jax.Array, v: jax.Array):
     return k, v
 
 
+@jax.named_scope("attn")
 def gqa_apply(params: Params, cfg: AttnConfig, x: jax.Array,
               positions: jax.Array | None = None) -> jax.Array:
     """Full-sequence (train / prefill) GQA."""
@@ -167,6 +168,7 @@ def gqa_apply(params: Params, cfg: AttnConfig, x: jax.Array,
     return out @ params["wo"].astype(x.dtype)
 
 
+@jax.named_scope("attn")
 def gqa_prefill(params: Params, cfg: AttnConfig, x: jax.Array,
                 positions: jax.Array | None = None):
     """Returns (attn_out, (k_cache, v_cache)) with caches [B, Hkv, S, D]."""
@@ -183,6 +185,7 @@ def gqa_prefill(params: Params, cfg: AttnConfig, x: jax.Array,
     return out @ params["wo"].astype(x.dtype), (kc, vc)
 
 
+@jax.named_scope("attn")
 def gqa_decode(params: Params, cfg: AttnConfig, x: jax.Array,
                cache: tuple[jax.Array, jax.Array], cache_len: jax.Array):
     """One-token decode. x: [B, 1, D_model]; cache [B, Hkv, S_max, D]."""
@@ -249,6 +252,7 @@ def _mla_qkv_full(params: Params, cfg: AttnConfig, x: jax.Array,
     return q_nope, q_rope, c_kv, k_rope
 
 
+@jax.named_scope("attn")
 def mla_apply(params: Params, cfg: AttnConfig, x: jax.Array,
               positions: jax.Array | None = None) -> jax.Array:
     """Full-sequence MLA: expand the latent to per-head K/V, then flash."""
@@ -272,6 +276,7 @@ def mla_apply(params: Params, cfg: AttnConfig, x: jax.Array,
     return out @ params["wo"].astype(x.dtype)
 
 
+@jax.named_scope("attn")
 def mla_prefill(params: Params, cfg: AttnConfig, x: jax.Array,
                 positions: jax.Array | None = None):
     """Cache only the latent (c_kv) + shared rope key — MLA's memory win."""
@@ -283,6 +288,7 @@ def mla_prefill(params: Params, cfg: AttnConfig, x: jax.Array,
     return out, (c_kv, k_rope[:, :, 0, :])
 
 
+@jax.named_scope("attn")
 def mla_decode(params: Params, cfg: AttnConfig, x: jax.Array,
                cache: tuple[jax.Array, jax.Array], cache_len: jax.Array,
                absorb: bool = False):

@@ -140,33 +140,39 @@ def _layer_apply_full(p: Params, arch: ArchConfig, h: jax.Array,
     # (otherwise GSPMD all-gathers the residual at every add —
     # measured ~7 hidden-sized gathers/layer on mistral, §Perf it.4).
     if arch.family in ("ssm", "hybrid"):
-        x = constrain(rms_norm(h, p["ln"]["scale"]), "tp_in", rt)
-        h = h + constrain(mamba2_apply(p["mamba"], ssm_config(arch), x),
-                          "hidden", rt)
+        with jax.named_scope("mlp"):
+            x = constrain(rms_norm(h, p["ln"]["scale"]), "tp_in", rt)
+            h = h + constrain(mamba2_apply(p["mamba"], ssm_config(arch), x),
+                              "hidden", rt)
         return constrain(h, "hidden", rt), aux
     acfg = attn_config(arch)
-    x = constrain(rms_norm(h, p["ln"]["scale"]), "tp_in", rt)
-    attn = mla_apply if arch.attn_type == "mla" else gqa_apply
-    h = h + constrain(attn(p["attn"], acfg, x), "hidden", rt)
-    x2 = constrain(rms_norm(h, p["ln2"]["scale"]), "tp_in", rt)
-    if arch.family == "moe":
-        h = h + constrain(moe_apply(p["moe"], moe_config(arch), x2),
-                          "hidden", rt)
-        aux = aux_load_balance_loss(p["moe"], moe_config(arch), x2)
-    else:
-        h = h + constrain(mlp_apply(p["mlp"], x2, arch.act), "hidden", rt)
+    with jax.named_scope("attn"):
+        x = constrain(rms_norm(h, p["ln"]["scale"]), "tp_in", rt)
+        attn = mla_apply if arch.attn_type == "mla" else gqa_apply
+        h = h + constrain(attn(p["attn"], acfg, x), "hidden", rt)
+    with jax.named_scope("mlp"):
+        x2 = constrain(rms_norm(h, p["ln2"]["scale"]), "tp_in", rt)
+        if arch.family == "moe":
+            h = h + constrain(moe_apply(p["moe"], moe_config(arch), x2),
+                              "hidden", rt)
+            aux = aux_load_balance_loss(p["moe"], moe_config(arch), x2)
+        else:
+            h = h + constrain(mlp_apply(p["mlp"], x2, arch.act), "hidden",
+                              rt)
     return constrain(h, "hidden", rt), aux
 
 
 def _shared_block_apply(p: Params, arch: ArchConfig, h: jax.Array,
                         emb0: jax.Array, rt: RuntimeConfig) -> jax.Array:
-    z = jnp.concatenate([h, emb0.astype(h.dtype)], axis=-1)
-    z = z @ p["w_cat"].astype(h.dtype)
     acfg = attn_config(arch)
-    x = rms_norm(z, p["ln"]["scale"])
-    z = z + gqa_apply(p["attn"], acfg, x)
-    x2 = rms_norm(z, p["ln2"]["scale"])
-    z = z + mlp_apply(p["mlp"], x2, arch.act)
+    with jax.named_scope("attn"):
+        z = jnp.concatenate([h, emb0.astype(h.dtype)], axis=-1)
+        z = z @ p["w_cat"].astype(h.dtype)
+        x = rms_norm(z, p["ln"]["scale"])
+        z = z + gqa_apply(p["attn"], acfg, x)
+    with jax.named_scope("mlp"):
+        x2 = rms_norm(z, p["ln2"]["scale"])
+        z = z + mlp_apply(p["mlp"], x2, arch.act)
     return h + z
 
 
@@ -213,12 +219,24 @@ def init_model(key: jax.Array, arch: ArchConfig,
 # ======================================================================
 # Forward (train / prefill), scan over stacked layers
 # ======================================================================
+@jax.named_scope("weight_cast")
 def _cast_blocks(blocks: Params, dtype) -> Params:
-    """Cast stacked weights to compute dtype ONCE, outside the layer
-    scan, so FSDP all-gathers move bf16 (not f32) bytes.  Norm scales
-    etc. are 1-D and stay f32 (rms_norm computes in f32 anyway)."""
+    """Cast the stacked layer matrices to the compute dtype ONCE, outside
+    the layer scan, so FSDP all-gathers move bf16 (not f32) bytes.  A
+    layer's matrices are rank >= 3 once stacked; its vectors (norm
+    scales etc.) are rank 2 and stay f32 (rms_norm computes in f32).
+    The layers cast each matrix to the compute dtype where they use it,
+    so casting the stack and then slicing gives the same bits as slicing
+    and then casting.
+
+    The barrier changes no value and adds no op.  Where the layers want
+    a stack in another layout, XLA folds the layout change and the cast
+    into one copy named after the copy's input: through the barrier that
+    input is this scope's, not the bare parameter, so the trace counts
+    the copy under ``weight_cast``."""
+    blocks = jax.lax.optimization_barrier(blocks)
     return jax.tree.map(
-        lambda x: x.astype(dtype) if (x.ndim >= 2 and
+        lambda x: x.astype(dtype) if (x.ndim >= 3 and
                                       x.dtype == jnp.float32) else x,
         blocks)
 
@@ -247,8 +265,9 @@ def _scan_layers(params: Params, arch: ArchConfig, h: jax.Array,
         layer = jax.checkpoint(
             one_layer, policy=jax.checkpoint_policies.nothing_saveable)
     blocks = _cast_blocks(params["blocks"], h.dtype)
-    h, auxs = jax.lax.scan(
-        layer, h, (blocks, jnp.arange(arch.n_layers)))
+    with jax.named_scope("layers"):
+        h, auxs = jax.lax.scan(
+            layer, h, (blocks, jnp.arange(arch.n_layers)))
     return h, jnp.sum(auxs)
 
 
@@ -257,17 +276,20 @@ def _encoder_forward(params: Params, arch: ArchConfig, frames: jax.Array,
     acfg = attn_config(arch, causal=False)
 
     def one_layer(h, bp):
-        x = rms_norm(h, bp["ln"]["scale"])
-        h = h + gqa_apply(bp["attn"], acfg, x)
-        x2 = rms_norm(h, bp["ln2"]["scale"])
-        h = h + mlp_apply(bp["mlp"], x2, arch.act)
+        with jax.named_scope("attn"):
+            x = rms_norm(h, bp["ln"]["scale"])
+            h = h + gqa_apply(bp["attn"], acfg, x)
+        with jax.named_scope("mlp"):
+            x2 = rms_norm(h, bp["ln2"]["scale"])
+            h = h + mlp_apply(bp["mlp"], x2, arch.act)
         return constrain(h, "hidden", rt), None
 
     layer = one_layer
     if rt.remat == "full":
         layer = jax.checkpoint(
             one_layer, policy=jax.checkpoint_policies.nothing_saveable)
-    h, _ = jax.lax.scan(layer, frames, params["enc_blocks"])
+    with jax.named_scope("layers"):
+        h, _ = jax.lax.scan(layer, frames, params["enc_blocks"])
     return rms_norm(h, params["enc_norm"]["scale"])
 
 
@@ -278,39 +300,58 @@ def _cross_decoder_forward(params: Params, arch: ArchConfig, h: jax.Array,
     xcfg = attn_config(arch, causal=False)
 
     def one_layer(hh, bp):
-        x = rms_norm(hh, bp["ln"]["scale"])
-        hh = hh + gqa_apply(bp["attn"], acfg, x)
-        xc = rms_norm(hh, bp["ln_cross"]["scale"])
-        # cross attention: q from decoder, k/v from encoder output
-        b, s, _ = xc.shape
-        hd = xcfg.head_dim
-        q = (xc @ bp["cross"]["wq"].astype(xc.dtype)).reshape(
-            b, s, xcfg.n_heads, hd)
-        k = (enc_out.astype(xc.dtype) @ bp["cross"]["wk"].astype(xc.dtype)
-             ).reshape(b, -1, xcfg.n_kv_heads, hd)
-        v = (enc_out.astype(xc.dtype) @ bp["cross"]["wv"].astype(xc.dtype)
-             ).reshape(b, -1, xcfg.n_kv_heads, hd)
-        o = flash_attention(jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2),
-                            jnp.swapaxes(v, 1, 2), causal=False)
-        o = jnp.swapaxes(o, 1, 2).reshape(b, s, xcfg.n_heads * hd)
-        hh = hh + o @ bp["cross"]["wo"].astype(xc.dtype)
-        x2 = rms_norm(hh, bp["ln2"]["scale"])
-        hh = hh + mlp_apply(bp["mlp"], x2, arch.act)
+        with jax.named_scope("attn"):
+            x = rms_norm(hh, bp["ln"]["scale"])
+            hh = hh + gqa_apply(bp["attn"], acfg, x)
+            xc = rms_norm(hh, bp["ln_cross"]["scale"])
+            # cross attention: q from decoder, k/v from encoder output
+            b, s, _ = xc.shape
+            hd = xcfg.head_dim
+            q = (xc @ bp["cross"]["wq"].astype(xc.dtype)).reshape(
+                b, s, xcfg.n_heads, hd)
+            k = (enc_out.astype(xc.dtype)
+                 @ bp["cross"]["wk"].astype(xc.dtype)
+                 ).reshape(b, -1, xcfg.n_kv_heads, hd)
+            v = (enc_out.astype(xc.dtype)
+                 @ bp["cross"]["wv"].astype(xc.dtype)
+                 ).reshape(b, -1, xcfg.n_kv_heads, hd)
+            o = flash_attention(jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2),
+                                jnp.swapaxes(v, 1, 2), causal=False)
+            o = jnp.swapaxes(o, 1, 2).reshape(b, s, xcfg.n_heads * hd)
+            hh = hh + o @ bp["cross"]["wo"].astype(xc.dtype)
+        with jax.named_scope("mlp"):
+            x2 = rms_norm(hh, bp["ln2"]["scale"])
+            hh = hh + mlp_apply(bp["mlp"], x2, arch.act)
         return constrain(hh, "hidden", rt), jnp.zeros((), jnp.float32)
 
     layer = one_layer
     if rt.remat == "full":
         layer = jax.checkpoint(
             one_layer, policy=jax.checkpoint_policies.nothing_saveable)
-    h, auxs = jax.lax.scan(layer, h, params["blocks"])
+    with jax.named_scope("layers"):
+        h, auxs = jax.lax.scan(layer, h, params["blocks"])
     return h, jnp.sum(auxs)
 
 
+@jax.named_scope("embed")
 def embed_tokens(params: Params, arch: ArchConfig, tokens: jax.Array,
                  rt: RuntimeConfig, compute_dtype) -> jax.Array:
     e = jnp.take(params["embed"], tokens, axis=0).astype(compute_dtype)
     return constrain(e * jnp.sqrt(arch.d_model).astype(compute_dtype),
                      "hidden", rt)
+
+
+@jax.named_scope("head")
+def _head(params: Params, h: jax.Array, compute_dtype,
+          last_only: bool = False) -> jax.Array:
+    """Final norm and logits, of the last position alone with
+    ``last_only``; the head is the embedding's transpose when tied."""
+    h = rms_norm(h, params["final_norm"]["scale"])
+    if last_only:
+        h = h[:, -1:, :]
+    head = params.get("head", None)
+    w = (params["embed"].T if head is None else head).astype(compute_dtype)
+    return h @ w
 
 
 def forward(params: Params, arch: ArchConfig, batch: dict[str, jax.Array],
@@ -327,9 +368,10 @@ def forward(params: Params, arch: ArchConfig, batch: dict[str, jax.Array],
     h = embed_tokens(params, arch, tokens, rt, cd)
 
     if arch.family == "vlm":
-        prefix = (batch["patches"].astype(cd)
-                  @ params["patch_proj"].astype(cd))
-        h = jnp.concatenate([prefix, h], axis=1)
+        with jax.named_scope("embed"):
+            prefix = (batch["patches"].astype(cd)
+                      @ params["patch_proj"].astype(cd))
+            h = jnp.concatenate([prefix, h], axis=1)
 
     if arch.is_encdec:
         enc_out = _encoder_forward(params, arch,
@@ -338,10 +380,7 @@ def forward(params: Params, arch: ArchConfig, batch: dict[str, jax.Array],
     else:
         h, aux = _scan_layers(params, arch, h, rt)
 
-    h = rms_norm(h, params["final_norm"]["scale"])
-    head = params.get("head", None)
-    w = (params["embed"].T if head is None else head).astype(cd)
-    logits = h @ w
+    logits = _head(params, h, cd)
     return constrain(logits, "logits", rt), aux
 
 
@@ -430,6 +469,16 @@ def _cross_attn_decode(bp: Params, arch: ArchConfig, x: jax.Array,
     return o @ bp["cross"]["wo"].astype(x.dtype)
 
 
+def _ffn(bp: Params, arch: ArchConfig, h: jax.Array) -> jax.Array:
+    """The feed-forward half of a decoder layer: norm, then the MLP (the
+    experts under ``moe``), added to the residual ``h``."""
+    with jax.named_scope("mlp"):
+        x = rms_norm(h, bp["ln2"]["scale"])
+        if arch.family == "moe":
+            return h + moe_apply(bp["moe"], moe_config(arch), x)
+        return h + mlp_apply(bp["mlp"], x, arch.act)
+
+
 def decode_step(params: Params, arch: ArchConfig, cache: Params,
                 tokens: jax.Array, rt: RuntimeConfig | None = None,
                 policy: DTypePolicy | None = None
@@ -450,25 +499,27 @@ def decode_step(params: Params, arch: ArchConfig, cache: Params,
             def layer(carry, x):
                 hh = carry
                 bp, ck, kr = x
-                xn = rms_norm(hh, bp["ln"]["scale"])
-                o, (ck, kr) = mla_decode(bp["attn"], acfg, xn, (ck, kr),
-                                         pos, absorb=rt.mla_absorb)
-                hh = hh + o
-                x2 = rms_norm(hh, bp["ln2"]["scale"])
-                if arch.family == "moe":
-                    hh = hh + moe_apply(bp["moe"], moe_config(arch), x2)
-                else:
-                    hh = hh + mlp_apply(bp["mlp"], x2, arch.act)
-                return hh, (ck, kr)
+                with jax.named_scope("attn"):
+                    xn = rms_norm(hh, bp["ln"]["scale"])
+                    o, (ck, kr) = mla_decode(bp["attn"], acfg, xn, (ck, kr),
+                                             pos, absorb=rt.mla_absorb)
+                    hh = hh + o
+                return _ffn(bp, arch, hh), (ck, kr)
 
-            h, (ckv, krope) = jax.lax.scan(layer, h, xs)
+            with jax.named_scope("layers"):
+                h, (ckv, krope) = jax.lax.scan(layer, h, xs)
             cache = {**cache, "c_kv": ckv, "k_rope": krope}
         else:
+            # the GQA path uses every layer matrix in the compute dtype
+            # (mla_decode keeps wk_b and wv_b in f32), so the stack is cast
+            # once, under its scope, where XLA would otherwise hoist each
+            # layer's cast out of the scan with no name
+            blocks = _cast_blocks(params["blocks"], cd)
             if arch.is_encdec:
-                xs = (params["blocks"], cache["k"], cache["v"],
+                xs = (blocks, cache["k"], cache["v"],
                       cache["cross_k"], cache["cross_v"])
             else:
-                xs = (params["blocks"], cache["k"], cache["v"])
+                xs = (blocks, cache["k"], cache["v"])
 
             def layer(carry, x):
                 hh = carry
@@ -476,20 +527,19 @@ def decode_step(params: Params, arch: ArchConfig, cache: Params,
                     bp, kc, vc, xk, xv = x
                 else:
                     bp, kc, vc = x
-                xn = rms_norm(hh, bp["ln"]["scale"])
-                o, (kc, vc) = gqa_decode(bp["attn"], acfg, xn, (kc, vc), pos)
-                hh = hh + o
-                if arch.is_encdec:
-                    xc = rms_norm(hh, bp["ln_cross"]["scale"])
-                    hh = hh + _cross_attn_decode(bp, arch, xc[:, 0], xk, xv)
-                x2 = rms_norm(hh, bp["ln2"]["scale"])
-                if arch.family == "moe":
-                    hh = hh + moe_apply(bp["moe"], moe_config(arch), x2)
-                else:
-                    hh = hh + mlp_apply(bp["mlp"], x2, arch.act)
-                return hh, (kc, vc)
+                with jax.named_scope("attn"):
+                    xn = rms_norm(hh, bp["ln"]["scale"])
+                    o, (kc, vc) = gqa_decode(bp["attn"], acfg, xn, (kc, vc),
+                                             pos)
+                    hh = hh + o
+                    if arch.is_encdec:
+                        xc = rms_norm(hh, bp["ln_cross"]["scale"])
+                        hh = hh + _cross_attn_decode(bp, arch, xc[:, 0],
+                                                     xk, xv)
+                return _ffn(bp, arch, hh), (kc, vc)
 
-            h, (kc, vc) = jax.lax.scan(layer, h, xs)
+            with jax.named_scope("layers"):
+                h, (kc, vc) = jax.lax.scan(layer, h, xs)
             cache = {**cache, "k": kc, "v": vc}
     else:  # ssm / hybrid
         scfg = ssm_config(arch)
@@ -500,9 +550,10 @@ def decode_step(params: Params, arch: ArchConfig, cache: Params,
         def layer(carry, x):
             hh, sk, sv = carry
             bp, hc, cc, idx = x
-            xn = rms_norm(hh, bp["ln"]["scale"])
-            o, (hc, cc) = mamba2_decode(bp["mamba"], scfg, xn, (hc, cc))
-            hh = hh + o
+            with jax.named_scope("mlp"):
+                xn = rms_norm(hh, bp["ln"]["scale"])
+                o, (hc, cc) = mamba2_decode(bp["mamba"], scfg, xn, (hc, cc))
+                hh = hh + o
 
             if arch.family == "hybrid" and every:
                 u = idx // every
@@ -510,17 +561,19 @@ def decode_step(params: Params, arch: ArchConfig, cache: Params,
                 def do_shared(args):
                     hh, sk, sv = args
                     sp = params["shared"]
-                    z = jnp.concatenate([hh, emb0.astype(hh.dtype)], -1)
-                    z = z @ sp["w_cat"].astype(hh.dtype)
-                    xn2 = rms_norm(z, sp["ln"]["scale"])
-                    ku, vu = sk[u], sv[u]
-                    o2, (ku, vu) = gqa_decode(sp["attn"], acfg, xn2,
-                                              (ku, vu), pos)
-                    z = z + o2
-                    x2 = rms_norm(z, sp["ln2"]["scale"])
-                    z = z + mlp_apply(sp["mlp"], x2, arch.act)
-                    sk = jax.lax.dynamic_update_index_in_dim(sk, ku, u, 0)
-                    sv = jax.lax.dynamic_update_index_in_dim(sv, vu, u, 0)
+                    with jax.named_scope("attn"):
+                        z = jnp.concatenate([hh, emb0.astype(hh.dtype)], -1)
+                        z = z @ sp["w_cat"].astype(hh.dtype)
+                        xn2 = rms_norm(z, sp["ln"]["scale"])
+                        ku, vu = sk[u], sv[u]
+                        o2, (ku, vu) = gqa_decode(sp["attn"], acfg, xn2,
+                                                  (ku, vu), pos)
+                        z = z + o2
+                        sk = jax.lax.dynamic_update_index_in_dim(sk, ku, u, 0)
+                        sv = jax.lax.dynamic_update_index_in_dim(sv, vu, u, 0)
+                    with jax.named_scope("mlp"):
+                        x2 = rms_norm(z, sp["ln2"]["scale"])
+                        z = z + mlp_apply(sp["mlp"], x2, arch.act)
                     return hh + z, sk, sv
 
                 hh, sk, sv = jax.lax.cond(
@@ -530,18 +583,16 @@ def decode_step(params: Params, arch: ArchConfig, cache: Params,
         if sk is None:
             sk = jnp.zeros((1,), jnp.float32)
             sv = jnp.zeros((1,), jnp.float32)
-        (h, sk, sv), (hc, cc) = jax.lax.scan(
-            layer, (h, sk, sv),
-            (params["blocks"], cache["ssm_h"], cache["ssm_conv"],
-             jnp.arange(arch.n_layers)))
+        with jax.named_scope("layers"):
+            (h, sk, sv), (hc, cc) = jax.lax.scan(
+                layer, (h, sk, sv),
+                (params["blocks"], cache["ssm_h"], cache["ssm_conv"],
+                 jnp.arange(arch.n_layers)))
         cache = {**cache, "ssm_h": hc, "ssm_conv": cc}
         if arch.family == "hybrid" and every:
             cache = {**cache, "shared_k": sk, "shared_v": sv}
 
-    h = rms_norm(h, params["final_norm"]["scale"])
-    head = params.get("head", None)
-    w = (params["embed"].T if head is None else head).astype(cd)
-    logits = h @ w
+    logits = _head(params, h, cd)
     cache = {**cache, "len": cache["len"] + 1}
     return constrain(logits, "logits", rt), cache
 
@@ -559,6 +610,11 @@ def prefill(params: Params, arch: ArchConfig, batch: dict[str, jax.Array],
     cache = make_cache(arch, cache_len, b, policy)
     h = embed_tokens(params, arch, tokens, rt, cd)
     acfg = attn_config(arch)
+    # the attention families use every layer weight in the compute dtype
+    # (the SSM mixer keeps some in f32): cast the stack once, as in decode
+    blocks = params["blocks"]
+    if arch.family in ("dense", "moe", "vlm") or arch.is_encdec:
+        blocks = _cast_blocks(blocks, cd)
 
     if arch.is_encdec:
         # encoder once; decoder prefill caches self-KV + per-layer cross-KV
@@ -568,84 +624,78 @@ def prefill(params: Params, arch: ArchConfig, batch: dict[str, jax.Array],
         xcfg = attn_config(arch, causal=False)
 
         def layer(hh, bp):
-            xn = rms_norm(hh, bp["ln"]["scale"])
-            o, (kc, vc) = gqa_prefill(bp["attn"], acfg, xn)
-            hh = hh + o
-            xc = rms_norm(hh, bp["ln_cross"]["scale"])
-            be, se, _ = enc_out.shape
-            q = (xc @ bp["cross"]["wq"].astype(cd)).reshape(
-                be, -1, xcfg.n_heads, hd)
-            xk = (enc_out.astype(cd) @ bp["cross"]["wk"].astype(cd)
-                  ).reshape(be, se, xcfg.n_kv_heads, hd)
-            xv = (enc_out.astype(cd) @ bp["cross"]["wv"].astype(cd)
-                  ).reshape(be, se, xcfg.n_kv_heads, hd)
-            o2 = flash_attention(jnp.swapaxes(q, 1, 2),
-                                 jnp.swapaxes(xk, 1, 2),
-                                 jnp.swapaxes(xv, 1, 2), causal=False)
-            o2 = o2.swapaxes(1, 2).reshape(be, -1, xcfg.n_heads * hd)
-            hh = hh + o2 @ bp["cross"]["wo"].astype(cd)
-            x2 = rms_norm(hh, bp["ln2"]["scale"])
-            hh = hh + mlp_apply(bp["mlp"], x2, arch.act)
-            return constrain(hh, "hidden", rt), (
-                kc, vc, jnp.swapaxes(xk, 1, 2), jnp.swapaxes(xv, 1, 2))
-
-        h, (kc, vc, xk, xv) = jax.lax.scan(layer, h, params["blocks"])
-        pad = ((0, 0), (0, 0), (0, 0), (0, cache_len - s), (0, 0))
-        cache["k"] = jnp.pad(kc.astype(cd), pad)
-        cache["v"] = jnp.pad(vc.astype(cd), pad)
-        s_enc = cache["cross_k"].shape[3]
-        cache["cross_k"] = xk[:, :, :, :s_enc].astype(cd)
-        cache["cross_v"] = xv[:, :, :, :s_enc].astype(cd)
-    elif arch.family in ("dense", "moe", "vlm"):
-        if arch.attn_type == "mla":
-            def layer(hh, bp):
-                xn = rms_norm(hh, bp["ln"]["scale"])
-                o, (ckv, kr) = mla_prefill(bp["attn"], acfg, xn)
-                hh = hh + o
-                x2 = rms_norm(hh, bp["ln2"]["scale"])
-                if arch.family == "moe":
-                    hh = hh + moe_apply(bp["moe"], moe_config(arch), x2)
-                else:
-                    hh = hh + mlp_apply(bp["mlp"], x2, arch.act)
-                return constrain(hh, "hidden", rt), (ckv, kr)
-
-            h, (ckv, kr) = jax.lax.scan(layer, h, params["blocks"])
-            cache["c_kv"] = jnp.pad(
-                ckv.astype(cd), ((0, 0), (0, 0), (0, cache_len - s), (0, 0)))
-            cache["k_rope"] = jnp.pad(
-                kr.astype(cd), ((0, 0), (0, 0), (0, cache_len - s), (0, 0)))
-        else:
-            def layer(hh, bp):
+            with jax.named_scope("attn"):
                 xn = rms_norm(hh, bp["ln"]["scale"])
                 o, (kc, vc) = gqa_prefill(bp["attn"], acfg, xn)
                 hh = hh + o
-                x2 = rms_norm(hh, bp["ln2"]["scale"])
-                if arch.family == "moe":
-                    hh = hh + moe_apply(bp["moe"], moe_config(arch), x2)
-                else:
-                    hh = hh + mlp_apply(bp["mlp"], x2, arch.act)
-                return constrain(hh, "hidden", rt), (kc, vc)
+                xc = rms_norm(hh, bp["ln_cross"]["scale"])
+                be, se, _ = enc_out.shape
+                q = (xc @ bp["cross"]["wq"].astype(cd)).reshape(
+                    be, -1, xcfg.n_heads, hd)
+                xk = (enc_out.astype(cd) @ bp["cross"]["wk"].astype(cd)
+                      ).reshape(be, se, xcfg.n_kv_heads, hd)
+                xv = (enc_out.astype(cd) @ bp["cross"]["wv"].astype(cd)
+                      ).reshape(be, se, xcfg.n_kv_heads, hd)
+                o2 = flash_attention(jnp.swapaxes(q, 1, 2),
+                                     jnp.swapaxes(xk, 1, 2),
+                                     jnp.swapaxes(xv, 1, 2), causal=False)
+                o2 = o2.swapaxes(1, 2).reshape(be, -1, xcfg.n_heads * hd)
+                hh = hh + o2 @ bp["cross"]["wo"].astype(cd)
+            hh = _ffn(bp, arch, hh)
+            return constrain(hh, "hidden", rt), (
+                kc, vc, jnp.swapaxes(xk, 1, 2), jnp.swapaxes(xv, 1, 2))
 
-            h, (kc, vc) = jax.lax.scan(layer, h, params["blocks"])
+        with jax.named_scope("layers"):
+            h, (kc, vc, xk, xv) = jax.lax.scan(layer, h, blocks)
             pad = ((0, 0), (0, 0), (0, 0), (0, cache_len - s), (0, 0))
             cache["k"] = jnp.pad(kc.astype(cd), pad)
             cache["v"] = jnp.pad(vc.astype(cd), pad)
+            s_enc = cache["cross_k"].shape[3]
+            cache["cross_k"] = xk[:, :, :, :s_enc].astype(cd)
+            cache["cross_v"] = xv[:, :, :, :s_enc].astype(cd)
+    elif arch.family in ("dense", "moe", "vlm"):
+        if arch.attn_type == "mla":
+            def layer(hh, bp):
+                with jax.named_scope("attn"):
+                    xn = rms_norm(hh, bp["ln"]["scale"])
+                    o, (ckv, kr) = mla_prefill(bp["attn"], acfg, xn)
+                    hh = hh + o
+                return constrain(_ffn(bp, arch, hh), "hidden", rt), (ckv, kr)
+
+            with jax.named_scope("layers"):
+                h, (ckv, kr) = jax.lax.scan(layer, h, blocks)
+                pad = ((0, 0), (0, 0), (0, cache_len - s), (0, 0))
+                cache["c_kv"] = jnp.pad(ckv.astype(cd), pad)
+                cache["k_rope"] = jnp.pad(kr.astype(cd), pad)
+        else:
+            def layer(hh, bp):
+                with jax.named_scope("attn"):
+                    xn = rms_norm(hh, bp["ln"]["scale"])
+                    o, (kc, vc) = gqa_prefill(bp["attn"], acfg, xn)
+                    hh = hh + o
+                return constrain(_ffn(bp, arch, hh), "hidden", rt), (kc, vc)
+
+            with jax.named_scope("layers"):
+                h, (kc, vc) = jax.lax.scan(layer, h, blocks)
+                pad = ((0, 0), (0, 0), (0, 0), (0, cache_len - s), (0, 0))
+                cache["k"] = jnp.pad(kc.astype(cd), pad)
+                cache["v"] = jnp.pad(vc.astype(cd), pad)
     elif arch.family in ("ssm", "hybrid"):
         def layer(hh, bp):
-            xn = rms_norm(hh, bp["ln"]["scale"])
-            o, (hf, conv_tail) = mamba2_apply(
-                bp["mamba"], ssm_config(arch), xn, return_state=True)
-            return constrain(hh + o, "hidden", rt), (hf, conv_tail)
+            with jax.named_scope("mlp"):
+                xn = rms_norm(hh, bp["ln"]["scale"])
+                o, (hf, conv_tail) = mamba2_apply(
+                    bp["mamba"], ssm_config(arch), xn, return_state=True)
+                hh = hh + o
+            return constrain(hh, "hidden", rt), (hf, conv_tail)
 
         # Note: prefill for hybrid ignores the shared attention block's
         # cache population here for brevity of the driver; serving tests
         # exercise decode_step from a zero cache instead.
-        h, (hf, conv_tail) = jax.lax.scan(layer, h, params["blocks"])
+        with jax.named_scope("layers"):
+            h, (hf, conv_tail) = jax.lax.scan(layer, h, blocks)
         cache["ssm_h"] = hf
         cache["ssm_conv"] = conv_tail.astype(cd)
-    h = rms_norm(h, params["final_norm"]["scale"])
-    head = params.get("head", None)
-    w = (params["embed"].T if head is None else head).astype(cd)
-    logits = h[:, -1:, :] @ w
+    logits = _head(params, h, cd, last_only=True)
     cache = {**cache, "len": jnp.asarray(s, jnp.int32)}
     return logits, cache

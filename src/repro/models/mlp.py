@@ -18,6 +18,7 @@ def mlp_init(key: jax.Array, d_model: int, d_ff: int, gated: bool) -> Params:
     return p
 
 
+@jax.named_scope("mlp")
 def mlp_apply(params: Params, x: jax.Array, act: str = "silu") -> jax.Array:
     f = ACTIVATIONS[act]
     up = x @ params["w_up"].astype(x.dtype)
