@@ -315,7 +315,12 @@ def phase_sharded(preset: str = "full", batch: int = SERVE["batch"],
             sparams, {"tokens": jax.device_put(tokens, tok_sh)})
         cache_sh = shd.to_named(shd.cache_pspecs(cache4, mesh, batch), mesh)
         cache4 = jax.device_put(cache4, cache_sh)
-        dec4 = jax.jit(decode, in_shardings=(param_sh, cache_sh, tok_sh))
+        # a step function of its own, traced under the mesh: JAX reuses a
+        # traced program whatever context it is called in, and the
+        # one-device trace holds the weight-streaming kernel, which XLA
+        # cannot partition
+        dec4 = jax.jit(make_decode_step(arch, rt, policy),
+                       in_shardings=(param_sh, cache_sh, tok_sh))
         got = [np.asarray(logits4[:, -1, :], np.float32)]
         for t in range(steps):     # teacher-forced with the one-device ids
             _, lg, cache4 = dec4(sparams, cache4,

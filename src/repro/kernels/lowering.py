@@ -35,6 +35,14 @@ coordinates, and the block function receives the grid coordinates and
 the tables ahead of its blocks.  This is how a block's position can
 depend on data (a gather's row ids) and how a per-row scalar (a decode
 row's valid length) reaches the body without a sub-(8, 128) block.
+
+An *accumulating* program (``accumulate=True``) revisits its output
+blocks: consecutive grid steps that map to the same output block keep
+it resident, and the block function receives the output blocks as they
+stand after its input blocks and returns their new values.  A block's
+first visit holds undefined values on Pallas (zeros in ``xla`` mode), so
+the body must overwrite it then — the pattern of a contraction tiled
+along its reduced axis.
 """
 from __future__ import annotations
 
@@ -119,7 +127,7 @@ def _block_starts(spec: Spec, coords: Sequence[jax.Array],
 def _xla_call(block_fn: Callable, grid: Sequence[int], in_specs: Sequence[Spec],
               out_specs: Sequence[Spec],
               out_shapes: Sequence[jax.ShapeDtypeStruct], args: Sequence,
-              n_scalar: int):
+              n_scalar: int, accumulate: bool):
     """Execute the blocked program as pure XLA ops (the interpreter-bypass
     path).  Each grid step slices its input blocks, runs the block
     function, and writes the output blocks back; XLA compiles the loop
@@ -133,6 +141,10 @@ def _xla_call(block_fn: Callable, grid: Sequence[int], in_specs: Sequence[Spec],
         ins = [lax.dynamic_slice(a, _block_starts(s, coords, scalars),
                                  s.block_shape)
                for a, s in zip(args, in_specs)]
+        if accumulate:
+            ins += [lax.dynamic_slice(o, _block_starts(s, coords, scalars),
+                                      s.block_shape)
+                    for o, s in zip(outs, out_specs)]
         res = block_fn(coords, *scalars, *ins) if n_scalar else block_fn(*ins)
         res = res if isinstance(res, (tuple, list)) else (res,)
         return [lax.dynamic_update_slice(o, v.astype(o.dtype),
@@ -147,13 +159,13 @@ def _xla_call(block_fn: Callable, grid: Sequence[int], in_specs: Sequence[Spec],
 
 
 def _pallas_wrap(block_fn: Callable, n_in: int, n_scalar: int,
-                 n_grid: int) -> Callable:
+                 n_grid: int, accumulate: bool) -> Callable:
     """Adapt a value->value block function to a Pallas ref kernel:
     whole-block loads, call, whole-block stores.  Scalar-prefetch refs
     stay refs (SMEM on the TPU) and are indexed by the body."""
     def kernel(*refs):
         scalars, refs = refs[:n_scalar], refs[n_scalar:]
-        ins = [r[...] for r in refs[:n_in]]
+        ins = [r[...] for r in (refs if accumulate else refs[:n_in])]
         if n_scalar:
             coords = tuple(pl.program_id(a) for a in range(n_grid))
             res = block_fn(coords, *scalars, *ins)
@@ -177,14 +189,18 @@ def grid_call(block_fn: Callable, *, grid: Sequence[int],
               in_specs: Sequence[Spec], out_specs: Sequence[Spec],
               out_shapes: Sequence[jax.ShapeDtypeStruct], mode: str,
               num_scalar_prefetch: int = 0,
-              unpack: bool | None = None) -> Callable:
+              unpack: bool | None = None, accumulate: bool = False,
+              name: str | None = None) -> Callable:
     """Build the executable for one blocked kernel program.
 
     Returns ``f(*args) -> out`` (single out_shape) or ``-> tuple``.
     ``mode`` must already be resolved ('pallas'|'interpret'|'xla').
     With ``num_scalar_prefetch = k`` the first k operands are int32
     tables: index maps are called as ``index_map(*coords, *tables)`` and
-    the block function as ``block_fn(coords, *tables, *blocks)``.
+    the block function as ``block_fn(coords, *tables, *blocks)``.  With
+    ``accumulate`` the output blocks follow the input blocks (see the
+    module's doc).  ``name`` names the Pallas kernel, and with it the
+    device op in a profile.
     """
     grid = tuple(int(g) for g in grid)
     out_shapes = list(out_shapes)
@@ -197,13 +213,15 @@ def grid_call(block_fn: Callable, *, grid: Sequence[int],
                              f"got {len(args)}")
         if mode == "xla":
             outs = _xla_call(block_fn, grid, in_specs, out_specs,
-                             out_shapes, args, n_scalar)
+                             out_shapes, args, n_scalar, accumulate)
         elif mode in ("pallas", "interpret"):
             outs = pl.pallas_call(
-                _pallas_wrap(block_fn, len(in_specs), n_scalar, len(grid)),
+                _pallas_wrap(block_fn, len(in_specs), n_scalar, len(grid),
+                             accumulate),
                 grid_spec=_grid_spec(grid, in_specs, out_specs, n_scalar),
                 out_shape=out_shapes,
                 interpret=(mode == "interpret"),
+                name=name,
             )(*args)
             outs = tuple(outs) if isinstance(outs, (tuple, list)) else (outs,)
         else:

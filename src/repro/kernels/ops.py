@@ -4,7 +4,8 @@ Dispatch: every wrapper resolves an execution mode (see
 ``lowering.resolve_mode``); ``kv_decode`` and ``ssd_chunk`` also
 resolve a block configuration (explicit argument > autotuned table in
 ``_autotune_cache.json`` > kernel default).  ``amm_gather`` serves one
-request per grid step and has no block size to tune.  The
+request per grid step and has no block size to tune; ``weight_stream``
+sizes its blocks from the weights' widths.  The
 default mode is *compiled* — real ``pallas_call`` lowering on TPU/GPU,
 the XLA grid path on CPU — and runs through a jit'd implementation
 with mode and blocks held static.
@@ -30,6 +31,7 @@ from repro.kernels.amm_gather import amm_gather_u32
 from repro.kernels.banked_kv_decode import banked_kv_decode
 from repro.kernels.lowering import resolve_mode
 from repro.kernels.ssd_scan import ssd_chunk_step
+from repro.kernels.weight_stream import weight_stream_matmul
 
 _UINT_FOR = {2: jnp.uint16, 4: jnp.uint32}
 
@@ -131,3 +133,24 @@ def ssd_chunk(x, dt, cum, B, C, h_in, interpret: bool | None = None,
                           n=B.shape[-1])["block_h"]
     fn = _ssd_chunk_impl if mode == "interpret" else _ssd_chunk
     return fn(x, dt, cum, B, C, h_in, mode, _pick_block(block_h, h))
+
+
+def _weight_stream_impl(x, ws, layer, mode):
+    flat = x.reshape(-1, x.shape[-1])
+    outs = weight_stream_matmul(flat, ws, layer, mode=mode)
+    return tuple(o.reshape(*x.shape[:-1], o.shape[-1]) for o in outs)
+
+
+_weight_stream = jax.jit(_weight_stream_impl, static_argnames=("mode",))
+
+
+def weight_stream(x: jax.Array, ws, layer: jax.Array,
+                  interpret: bool | None = None, mode: str | None = None
+                  ) -> tuple[jax.Array, ...]:
+    """``tuple(x @ w[layer].astype(x.dtype) for w in ws)`` with float32
+    accumulation, each float32 block of the stacks read once (see
+    weight_stream.py).  x: [..., K]; ws: stacks [L, K, N_i]; layer: int
+    scalar.  Returns [..., N_i] in ``x``'s dtype."""
+    mode = resolve_mode(interpret, mode)
+    fn = _weight_stream_impl if mode == "interpret" else _weight_stream
+    return fn(x, tuple(ws), layer, mode)

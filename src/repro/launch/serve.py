@@ -2,6 +2,8 @@
 the model's dense KV cache (example application for the inference
 shapes).  The decode attention is the plain jnp path in
 ``models/attention.py``; the banked Pallas decode kernel is not on it.
+The decode step's layer matmuls (GQA models) stream the float32 weights
+through ``kernels/weight_stream.py``.
 
   PYTHONPATH=src python -m repro.launch.serve --arch qwen3-1.7b \
       --preset tiny --batch 4 --prompt-len 64 --gen 32

@@ -41,6 +41,13 @@ def tp_hint() -> int:
     return _TP_HINT.get()
 
 
+def partitioned() -> bool:
+    """True while a launcher lowers for a mesh (its activation sharder
+    is installed): the program is partitioned by XLA, which cannot
+    partition a custom kernel, so models keep such kernels off it."""
+    return _SHARDER.get() is not None
+
+
 def constrain(x: jax.Array, role: str, rt: Any = None) -> jax.Array:
     fn = _SHARDER.get()
     if fn is None:

@@ -22,8 +22,8 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from repro.models.common import (Params, apply_rope, dense_init, norm_init,
-                                 rms_norm)
+from repro.models.common import (Params, apply_rope, cast_matmul, dense_init,
+                                 norm_init, rms_norm)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -129,12 +129,13 @@ def gqa_init(key: jax.Array, cfg: AttnConfig) -> Params:
 
 
 def _project_qkv(params: Params, cfg: AttnConfig, x: jax.Array,
-                 positions: jax.Array):
+                 positions: jax.Array, matmul=cast_matmul):
     b, s, _ = x.shape
     hd = cfg.head_dim
-    q = (x @ params["wq"].astype(x.dtype)).reshape(b, s, cfg.n_heads, hd)
-    k = (x @ params["wk"].astype(x.dtype)).reshape(b, s, cfg.n_kv_heads, hd)
-    v = (x @ params["wv"].astype(x.dtype)).reshape(b, s, cfg.n_kv_heads, hd)
+    q, k, v = matmul(x, params["wq"], params["wk"], params["wv"])
+    q = q.reshape(b, s, cfg.n_heads, hd)
+    k = k.reshape(b, s, cfg.n_kv_heads, hd)
+    v = v.reshape(b, s, cfg.n_kv_heads, hd)
     if cfg.qk_norm:
         q = rms_norm(q, params["q_norm"]["scale"])
         k = rms_norm(k, params["k_norm"]["scale"])
@@ -187,12 +188,14 @@ def gqa_prefill(params: Params, cfg: AttnConfig, x: jax.Array,
 
 @jax.named_scope("attn")
 def gqa_decode(params: Params, cfg: AttnConfig, x: jax.Array,
-               cache: tuple[jax.Array, jax.Array], cache_len: jax.Array):
-    """One-token decode. x: [B, 1, D_model]; cache [B, Hkv, S_max, D]."""
+               cache: tuple[jax.Array, jax.Array], cache_len: jax.Array,
+               matmul=cast_matmul):
+    """One-token decode. x: [B, 1, D_model]; cache [B, Hkv, S_max, D].
+    ``matmul`` multiplies by the projections (``common.cast_matmul``)."""
     b = x.shape[0]
     hd = cfg.head_dim
     positions = jnp.full((1,), cache_len, jnp.int32)
-    q, k, v = _project_qkv(params, cfg, x, positions)
+    q, k, v = _project_qkv(params, cfg, x, positions, matmul)
     kc, vc = cache
     kc = jax.lax.dynamic_update_slice_in_dim(
         kc, jnp.swapaxes(k, 1, 2).astype(kc.dtype), cache_len, axis=2)
@@ -209,7 +212,8 @@ def gqa_decode(params: Params, cfg: AttnConfig, x: jax.Array,
     w = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bhgs,bhsd->bhgd", w, vc.astype(jnp.float32))
     out = out.reshape(b, 1, cfg.n_heads * hd).astype(x.dtype)
-    return out @ params["wo"].astype(x.dtype), (kc, vc)
+    (out,) = matmul(out, params["wo"])
+    return out, (kc, vc)
 
 
 # ======================================================================

@@ -98,6 +98,17 @@ def apply_rope(x: jax.Array, positions: jax.Array,
 
 
 # ----------------------------------------------------------------------
+# Matmuls
+# ----------------------------------------------------------------------
+def cast_matmul(x: jax.Array, *ws: jax.Array) -> tuple[jax.Array, ...]:
+    """``x @ w`` for each weight, each cast to ``x``'s dtype where it is
+    used.  The layers take their matmul as an argument with this as the
+    default; the decode step passes one that streams the weights from
+    their layer stacks (``models/lm.py``)."""
+    return tuple(x @ w.astype(x.dtype) for w in ws)
+
+
+# ----------------------------------------------------------------------
 # Activations
 # ----------------------------------------------------------------------
 def squared_relu(x: jax.Array) -> jax.Array:

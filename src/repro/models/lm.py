@@ -26,13 +26,14 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig, RuntimeConfig, ShapeConfig
-from repro.launch.sharding import constrain
+from repro.kernels import weight_stream
+from repro.launch.sharding import constrain, partitioned
 from repro.models.attention import (AttnConfig, flash_attention, gqa_apply,
                                     gqa_decode, gqa_init, gqa_prefill,
                                     mla_apply, mla_decode, mla_init,
                                     mla_prefill)
-from repro.models.common import (DTypePolicy, Params, dense_init, norm_init,
-                                 rms_norm, truncated_normal_init)
+from repro.models.common import (DTypePolicy, Params, cast_matmul, dense_init,
+                                 norm_init, rms_norm, truncated_normal_init)
 from repro.models.mlp import mlp_apply, mlp_init
 from repro.models.moe import MoEConfig, aux_load_balance_loss, moe_apply, moe_init
 from repro.models.ssm import SSMConfig, mamba2_apply, mamba2_decode, mamba2_init
@@ -227,7 +228,9 @@ def _cast_blocks(blocks: Params, dtype) -> Params:
     scales etc.) are rank 2 and stay f32 (rms_norm computes in f32).
     The layers cast each matrix to the compute dtype where they use it,
     so casting the stack and then slicing gives the same bits as slicing
-    and then casting.
+    and then casting.  Training and prefill cast every stack here; the
+    GQA decode step only what its weight-streaming matmul does not read
+    (MoE experts, cross attention): it writes no copy of the rest.
 
     The barrier changes no value and adds no op.  Where the layers want
     a stack in another layout, XLA folds the layout change and the cast
@@ -469,14 +472,43 @@ def _cross_attn_decode(bp: Params, arch: ArchConfig, x: jax.Array,
     return o @ bp["cross"]["wo"].astype(x.dtype)
 
 
-def _ffn(bp: Params, arch: ArchConfig, h: jax.Array) -> jax.Array:
+def _ffn(bp: Params, arch: ArchConfig, h: jax.Array,
+         matmul=cast_matmul) -> jax.Array:
     """The feed-forward half of a decoder layer: norm, then the MLP (the
-    experts under ``moe``), added to the residual ``h``."""
+    experts under ``moe``), added to the residual ``h``.  ``matmul`` is
+    the dense MLP's (``common.cast_matmul``)."""
     with jax.named_scope("mlp"):
         x = rms_norm(h, bp["ln2"]["scale"])
         if arch.family == "moe":
             return h + moe_apply(bp["moe"], moe_config(arch), x)
-        return h + mlp_apply(bp["mlp"], x, arch.act)
+        return h + mlp_apply(bp["mlp"], x, arch.act, matmul)
+
+
+def _split_stacks(blocks: Params, names: tuple[str, ...]
+                  ) -> tuple[Params, Params]:
+    """The matrix stacks ``[L, in, out]`` right under ``blocks[name]``
+    for each of ``names`` (the norms there are dicts), and the rest of
+    ``blocks``."""
+    mats, rest = {}, dict(blocks)
+    for name in names:
+        if name in blocks:
+            sub = blocks[name]
+            mats[name] = {k: w for k, w in sub.items()
+                          if not isinstance(w, dict)}
+            rest[name] = {k: w for k, w in sub.items() if isinstance(w, dict)}
+    return mats, rest
+
+
+def _stack_matmul(layer: jax.Array):
+    """The matmul of a decode layer whose weights are whole layer stacks
+    ``[L, in, out]``: ``x @ w[layer]`` in ``x``'s dtype.  The
+    weight-streaming kernel reads each float32 block of the layer once
+    and rounds it in VMEM, so no bfloat16 copy of a stack is written.
+    A program that a launcher partitions over a mesh slices and casts in
+    XLA instead, which partitions the dot; it cannot partition a kernel."""
+    if partitioned():
+        return lambda x, *ws: cast_matmul(x, *(w[layer] for w in ws))
+    return lambda x, *ws: weight_stream(x, ws, layer)
 
 
 def decode_step(params: Params, arch: ArchConfig, cache: Params,
@@ -510,33 +542,33 @@ def decode_step(params: Params, arch: ArchConfig, cache: Params,
                 h, (ckv, krope) = jax.lax.scan(layer, h, xs)
             cache = {**cache, "c_kv": ckv, "k_rope": krope}
         else:
-            # the GQA path uses every layer matrix in the compute dtype
-            # (mla_decode keeps wk_b and wv_b in f32), so the stack is cast
-            # once, under its scope, where XLA would otherwise hoist each
-            # layer's cast out of the scan with no name
-            blocks = _cast_blocks(params["blocks"], cd)
+            # attention's and the dense MLP's matrices stay whole float32
+            # stacks, closed over by the scan (scanning them would slice
+            # each layer out), and are read by the layer's matmul
+            # (_stack_matmul); the rest that is cast (MoE experts, cross
+            # attention) is cast once, under its scope, where XLA would
+            # otherwise hoist each layer's cast out of the scan with no name
+            mats, rest = _split_stacks(params["blocks"], ("attn", "mlp"))
+            xs = (_cast_blocks(rest, cd), jnp.arange(arch.n_layers),
+                  cache["k"], cache["v"])
             if arch.is_encdec:
-                xs = (blocks, cache["k"], cache["v"],
-                      cache["cross_k"], cache["cross_v"])
-            else:
-                xs = (blocks, cache["k"], cache["v"])
+                xs += (cache["cross_k"], cache["cross_v"])
 
             def layer(carry, x):
                 hh = carry
-                if arch.is_encdec:
-                    bp, kc, vc, xk, xv = x
-                else:
-                    bp, kc, vc = x
+                bp, idx, kc, vc = x[:4]
+                bp = {**bp, **{k: {**bp[k], **m} for k, m in mats.items()}}
+                matmul = _stack_matmul(idx)
                 with jax.named_scope("attn"):
                     xn = rms_norm(hh, bp["ln"]["scale"])
                     o, (kc, vc) = gqa_decode(bp["attn"], acfg, xn, (kc, vc),
-                                             pos)
+                                             pos, matmul)
                     hh = hh + o
                     if arch.is_encdec:
                         xc = rms_norm(hh, bp["ln_cross"]["scale"])
                         hh = hh + _cross_attn_decode(bp, arch, xc[:, 0],
-                                                     xk, xv)
-                return _ffn(bp, arch, hh), (kc, vc)
+                                                     *x[4:])
+                return _ffn(bp, arch, hh, matmul), (kc, vc)
 
             with jax.named_scope("layers"):
                 h, (kc, vc) = jax.lax.scan(layer, h, xs)

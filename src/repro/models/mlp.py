@@ -4,7 +4,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.models.common import ACTIVATIONS, Params, dense_init
+from repro.models.common import ACTIVATIONS, Params, cast_matmul, dense_init
 
 
 def mlp_init(key: jax.Array, d_model: int, d_ff: int, gated: bool) -> Params:
@@ -19,11 +19,16 @@ def mlp_init(key: jax.Array, d_model: int, d_ff: int, gated: bool) -> Params:
 
 
 @jax.named_scope("mlp")
-def mlp_apply(params: Params, x: jax.Array, act: str = "silu") -> jax.Array:
+def mlp_apply(params: Params, x: jax.Array, act: str = "silu",
+              matmul=cast_matmul) -> jax.Array:
+    """The gated (or plain) MLP; ``matmul(x, *ws)`` multiplies ``x`` by
+    each weight (see ``common.cast_matmul``), gate and up in one call."""
     f = ACTIVATIONS[act]
-    up = x @ params["w_up"].astype(x.dtype)
     if "w_gate" in params:
-        up = f(x @ params["w_gate"].astype(x.dtype)) * up
+        up, gate = matmul(x, params["w_up"], params["w_gate"])
+        up = f(gate) * up
     else:
+        (up,) = matmul(x, params["w_up"])
         up = f(up)
-    return up @ params["w_down"].astype(x.dtype)
+    (out,) = matmul(up, params["w_down"])
+    return out

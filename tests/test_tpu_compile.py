@@ -5,16 +5,19 @@ described, not attached.  These tests guard what interpret-mode and
 XLA-path tests cannot see — Mosaic's block-shape and VMEM rules, and
 whether a real-width step program compiles at all:
 
-  * the three Pallas kernels at real widths (qwen3-1.7b decode geometry,
-    mamba2-130m chunk geometry, qwen3's embedding table), each lowered
-    with Mosaic (``tpu_custom_call`` in the HLO);
-  * the qwen3-1.7b full-width decode step on one chip.
+  * the four Pallas kernels at real widths (qwen3-1.7b decode geometry,
+    mamba2-130m chunk geometry, qwen3's embedding table, qwen3's layer
+    matrices), each lowered with Mosaic (``tpu_custom_call`` in the HLO);
+  * the qwen3-1.7b full-width decode step on one chip, whose layer
+    matrices are all read by the weight-streaming kernel.
 
 The topology is described inside a module fixture (only the pytest
 worker that runs this file loads the TPU compiler), and the persistent
 compilation cache is off around the compiles.
 """
 import os
+import re
+from collections import Counter
 
 import pytest
 
@@ -89,7 +92,25 @@ def test_amm_gather_compiles_with_mosaic(one_chip):
     assert "tpu_custom_call" in c.as_text()
 
 
-def test_qwen3_decode_step_compiles_full_width(one_chip):
+@pytest.mark.parametrize("k,widths", [
+    (2048, (2048, 1024, 1024)),     # q, k, v
+    (2048, (2048,)),                # o
+    (2048, (6144, 6144)),           # gate, up
+    (6144, (2048,)),                # down
+    (14336, (4096,)),               # llama-3-8b's down: K-tiled
+])
+def test_weight_stream_compiles_with_mosaic(one_chip, k, widths):
+    from repro.kernels import weight_stream
+
+    c = _compile(lambda x, l, *ws: weight_stream(x, ws, l, mode="pallas"),
+                 _shape(one_chip, (8, 1, k), jnp.bfloat16),
+                 _shape(one_chip, (), jnp.int32),
+                 *[_shape(one_chip, (28, k, n), jnp.float32) for n in widths])
+    assert "tpu_custom_call" in c.as_text()
+    assert c.memory_analysis().temp_size_in_bytes < 2**20
+
+
+def test_qwen3_decode_step_compiles_full_width(one_chip, monkeypatch):
     from repro.configs import get_arch
     from repro.configs.base import RuntimeConfig
     from repro.launch.steps import make_decode_step
@@ -104,8 +125,24 @@ def test_qwen3_decode_step_compiles_full_width(one_chip):
     params = place(jax.eval_shape(
         lambda k: init_model(k, arch, policy), jax.random.PRNGKey(0)))
     cache = place(jax.eval_shape(lambda: make_cache(arch, 1152, 8, policy)))
+    # the model asks the backend for its kernel mode, and sees the CPU
+    monkeypatch.setenv("REPRO_KERNEL_MODE", "pallas")
     c = _compile(make_decode_step(arch, RuntimeConfig(remat="none"), policy),
                  params, cache, _shape(one_chip, (8, 1), jnp.int32))
     mem = c.memory_analysis()
     used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert used < 16e9, f"decode step needs {used / 2**30:.1f} GiB"
+    # every layer matrix is read as its f32 stack by a kernel, and no
+    # stack is cast: the program holds no bf16 copy of the stacks (2.8 GB)
+    hlo = c.as_text()
+    stacks = [x for x in jax.tree.leaves(params["blocks"]) if x.ndim == 3]
+    shape = lambda x: f"[{','.join(map(str, x.shape))}]"     # noqa: E731
+    assert not [ln for ln in hlo.splitlines()
+                if any(f"= bf16{shape(x)}" in ln and " convert(" in ln
+                       for x in stacks)]
+    read = Counter(m for ln in hlo.splitlines()
+                   if 'custom_call_target="tpu_custom_call"' in ln
+                   for m in re.findall(r"f32(\[\d+,\d+,\d+\])", ln))
+    assert read == Counter(shape(x) for x in stacks)
+    bf16_stacks = sum(x.size * 2 for x in stacks)
+    assert mem.temp_size_in_bytes < bf16_stacks
