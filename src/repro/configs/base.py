@@ -34,6 +34,10 @@ class ArchConfig:
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
     rope_head_dim: int = 0
+    # --- muP scalings (minicpm3); the defaults add no op ---
+    embed_scale: float = 0.0       # embedding multiplier; 0 -> sqrt(d_model)
+    residual_scale: float = 1.0    # multiplier of every residual branch
+    head_divisor: float = 1.0      # last hidden state divided before the head
     # --- MoE ---
     n_experts: int = 0
     top_k: int = 0
@@ -153,6 +157,5 @@ class RuntimeConfig:
     accum_steps: int = 1
     seq_shard_acts: bool = False       # Megatron-SP boundary activations
     kv_shard: str = "heads"            # heads | seq
-    mla_absorb: bool = False
     remat: str = "full"                # full | none
     axis_profile: str = "tp"           # tp (Megatron) | dp (pure FSDP-256)

@@ -2,8 +2,9 @@
 the model's dense KV cache (example application for the inference
 shapes).  The decode attention is the plain jnp path in
 ``models/attention.py``; the banked Pallas decode kernel is not on it.
-The decode step's layer matmuls (GQA models) stream the float32 weights
-through ``kernels/weight_stream.py``.
+The decode step's layer matmuls stream the float32 weights through
+``kernels/weight_stream.py``; MLA models decode in the absorbed latent
+form.
 
   PYTHONPATH=src python -m repro.launch.serve --arch qwen3-1.7b \
       --preset tiny --batch 4 --prompt-len 64 --gen 32
@@ -33,14 +34,13 @@ def main(argv=None) -> dict:
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=64)
     ap.add_argument("--gen", type=int, default=32)
-    ap.add_argument("--mla-absorb", action="store_true")
     args = ap.parse_args(argv)
     enable_compile_cache()
 
     arch = get_arch(args.arch)
     if args.preset == "tiny":
         arch = tiny_variant(arch)
-    rt = RuntimeConfig(remat="none", mla_absorb=args.mla_absorb)
+    rt = RuntimeConfig(remat="none")
     policy = DTypePolicy.standard()
 
     # the paper's planner: pick the memory layout for this serving shape

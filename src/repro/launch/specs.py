@@ -71,7 +71,6 @@ def resolve_runtime(arch: ArchConfig, shape: ShapeConfig,
         seq_shard_acts=(arch.d_model >= 6144 or shape.seq_len >= 32768)
         and axis_profile == "tp",
         kv_shard="auto",
-        mla_absorb=profile == "opt",
         remat="full" if shape.kind == "train" else "none",
         axis_profile=axis_profile,
     )

@@ -25,6 +25,8 @@ import jax.numpy as jnp
 from repro.models.common import (Params, apply_rope, cast_matmul, dense_init,
                                  norm_init, rms_norm)
 
+HIGHEST = jax.lax.Precision.HIGHEST
+
 
 @dataclasses.dataclass(frozen=True)
 class AttnConfig:
@@ -50,6 +52,12 @@ class AttnConfig:
 # ======================================================================
 # Blockwise (flash-style) attention core
 # ======================================================================
+# one KV block's scores, [B, Hq, Sq, block_kv] in float32, are the scan's
+# largest temporary: a long or wide query halves the block (to 128 at
+# least) until they fit this many bytes
+SCORE_BLOCK_BYTES = 2**29
+
+
 def _flash_block_scan(q, k, v, causal: bool, q_offset, block_kv: int,
                       bias=None):
     """Online-softmax attention.
@@ -63,6 +71,8 @@ def _flash_block_scan(q, k, v, causal: bool, q_offset, block_kv: int,
     g = hq // hkv
     qg = q.reshape(b, hkv, g, sq, d)
     scale = 1.0 / jnp.sqrt(d).astype(jnp.float32)
+    while block_kv > 128 and b * hq * sq * block_kv * 4 > SCORE_BLOCK_BYTES:
+        block_kv //= 2
 
     n_blocks = -(-skv // block_kv)
     pad = n_blocks * block_kv - skv
@@ -240,26 +250,30 @@ def mla_init(key: jax.Array, cfg: AttnConfig) -> Params:
 
 
 def _mla_qkv_full(params: Params, cfg: AttnConfig, x: jax.Array,
-                  positions: jax.Array):
+                  positions: jax.Array, matmul=cast_matmul):
+    """The queries (no-rope and rope parts, per head), the normed latent
+    ``c_kv`` and the shared rope key of ``x``.  ``matmul`` multiplies by
+    the projections (``common.cast_matmul``); q's and kv's down
+    projections share one call, one pass over ``x``."""
     b, s, _ = x.shape
     hd, r, kvr = cfg.head_dim, cfg.rope_head_dim, cfg.kv_lora_rank
-    qa = rms_norm(x @ params["wq_a"].astype(x.dtype),
-                  params["q_a_norm"]["scale"])
-    q = (qa @ params["wq_b"].astype(x.dtype)).reshape(b, s, cfg.n_heads, hd + r)
+    qa, kv = matmul(x, params["wq_a"], params["wkv_a"])      # kv: [B,S,kvr+r]
+    qa = rms_norm(qa, params["q_a_norm"]["scale"])
+    (q,) = matmul(qa, params["wq_b"])
+    q = q.reshape(b, s, cfg.n_heads, hd + r)
     q_nope, q_rope = q[..., :hd], q[..., hd:]
     q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
-
-    kv = x @ params["wkv_a"].astype(x.dtype)                  # [B,S,kvr+r]
     c_kv = rms_norm(kv[..., :kvr], params["kv_a_norm"]["scale"])
     k_rope = apply_rope(kv[..., kvr:][:, :, None, :], positions,
                         cfg.rope_theta)                        # [B,S,1,r]
     return q_nope, q_rope, c_kv, k_rope
 
 
-@jax.named_scope("attn")
-def mla_apply(params: Params, cfg: AttnConfig, x: jax.Array,
-              positions: jax.Array | None = None) -> jax.Array:
-    """Full-sequence MLA: expand the latent to per-head K/V, then flash."""
+def _mla_expanded(params: Params, cfg: AttnConfig, x: jax.Array,
+                  positions: jax.Array | None):
+    """Full-sequence MLA: expand the latent to per-head K/V, then flash.
+    Returns the attention's output and the latent and rope key it
+    computed on the way."""
     b, s, _ = x.shape
     hd, r = cfg.head_dim, cfg.rope_head_dim
     if positions is None:
@@ -277,77 +291,75 @@ def mla_apply(params: Params, cfg: AttnConfig, x: jax.Array,
         jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2),
         jnp.swapaxes(v_pad, 1, 2), causal=cfg.causal, block_kv=cfg.block_kv)
     out = jnp.swapaxes(out, 1, 2)[..., :hd].reshape(b, s, cfg.n_heads * hd)
-    return out @ params["wo"].astype(x.dtype)
+    return out @ params["wo"].astype(x.dtype), c_kv, k_rope
+
+
+@jax.named_scope("attn")
+def mla_apply(params: Params, cfg: AttnConfig, x: jax.Array,
+              positions: jax.Array | None = None) -> jax.Array:
+    """Full-sequence (train / prefill) MLA, in the expanded form."""
+    return _mla_expanded(params, cfg, x, positions)[0]
 
 
 @jax.named_scope("attn")
 def mla_prefill(params: Params, cfg: AttnConfig, x: jax.Array,
                 positions: jax.Array | None = None):
     """Cache only the latent (c_kv) + shared rope key — MLA's memory win."""
-    b, s, _ = x.shape
-    if positions is None:
-        positions = jnp.arange(s)
-    out = mla_apply(params, cfg, x, positions)
-    q_nope, q_rope, c_kv, k_rope = _mla_qkv_full(params, cfg, x, positions)
+    out, c_kv, k_rope = _mla_expanded(params, cfg, x, positions)
     return out, (c_kv, k_rope[:, :, 0, :])
 
 
 @jax.named_scope("attn")
 def mla_decode(params: Params, cfg: AttnConfig, x: jax.Array,
                cache: tuple[jax.Array, jax.Array], cache_len: jax.Array,
-               absorb: bool = False):
-    """One-token MLA decode against latent cache (c_kv [B,S,kvr],
-    k_rope [B,S,r]).
-
-    absorb=False (baseline): expand latent to per-head K/V each step.
-    absorb=True (optimized): score/accumulate in latent space — the
-    W_UK/W_UV absorption trick; O(S*kvr) instead of O(S*H*hd) bytes.
-    """
+               matmul=cast_matmul, layer: jax.Array | None = None):
+    """One-token MLA decode against the latent cache (c_kv [B,S,kvr],
+    k_rope [B,S,r]; with ``layer``, the stacks [L,B,S,...] of every
+    layer, of which layer ``layer``'s is read), in the absorbed form:
+    W_UK takes the query into the latent space, where it is scored
+    against ``c_kv`` itself, and W_UV takes the latent context out, so a
+    step reads the cache's S*(kvr+r) numbers a row and never expands it
+    to per-head K and V.  Returns the output and this layer's cache.
+    Under the scope ``latent``: the cache's read (the layer's slice of
+    the stacks) and write, the query's absorption, the scores against
+    ``c_kv`` and the rope key, the mask, the softmax, the latent context
+    and its up-projection; the cache is read in its own dtype with
+    float32 accumulation.  ``matmul`` multiplies by the other
+    projections (``common.cast_matmul``)."""
     b = x.shape[0]
     hd, r, kvr = cfg.head_dim, cfg.rope_head_dim, cfg.kv_lora_rank
+    f32 = jnp.float32
     positions = jnp.full((1,), cache_len, jnp.int32)
-    q_nope, q_rope, c_new, k_rope_new = _mla_qkv_full(params, cfg, x, positions)
-    c_cache, r_cache = cache
-    c_cache = jax.lax.dynamic_update_slice_in_dim(
-        c_cache, c_new.astype(c_cache.dtype), cache_len, axis=1)
-    r_cache = jax.lax.dynamic_update_slice_in_dim(
-        r_cache, k_rope_new[:, :, 0, :].astype(r_cache.dtype), cache_len, axis=1)
-    s_max = c_cache.shape[1]
-    valid = (jnp.arange(s_max)[None, None, :] <= cache_len)
-
-    q_nope_h = q_nope[:, 0]                       # [B, H, hd]
-    q_rope_h = q_rope[:, 0]                       # [B, H, r]
-    scale = 1.0 / jnp.sqrt(hd + r)
-
-    if absorb:
+    q_nope, q_rope, c_new, k_rope_new = _mla_qkv_full(params, cfg, x,
+                                                      positions, matmul)
+    with jax.named_scope("latent"):
+        c_cache, r_cache = cache if layer is None else (
+            jax.lax.dynamic_index_in_dim(c, layer, keepdims=False)
+            for c in cache)
+        c_cache = jax.lax.dynamic_update_slice_in_dim(
+            c_cache, c_new.astype(c_cache.dtype), cache_len, axis=1)
+        r_cache = jax.lax.dynamic_update_slice_in_dim(
+            r_cache, k_rope_new[:, :, 0, :].astype(r_cache.dtype), cache_len,
+            axis=1)
+        cdt = c_cache.dtype
         wk = params["wk_b"].reshape(kvr, cfg.n_heads, hd)
-        q_lat = jnp.einsum("bhd,khd->bhk", q_nope_h.astype(jnp.float32),
-                           wk.astype(jnp.float32))            # [B,H,kvr]
-        s_lat = jnp.einsum("bhk,bsk->bhs", q_lat,
-                           c_cache.astype(jnp.float32))
-        s_rope = jnp.einsum("bhr,bsr->bhs", q_rope_h.astype(jnp.float32),
-                            r_cache.astype(jnp.float32))
-        scores = (s_lat + s_rope) * scale
-        scores = jnp.where(valid, scores, -jnp.inf)
-        w = jax.nn.softmax(scores, axis=-1)
-        ctx_lat = jnp.einsum("bhs,bsk->bhk", w, c_cache.astype(jnp.float32))
+        # W_UK and W_UV are float32 slices of their stacks: at full
+        # precision XLA keeps them so, where the default precision would
+        # have it write a bfloat16 copy of both stacks every step
+        q_lat = jnp.einsum("bhd,khd->bhk", q_nope[:, 0].astype(f32),
+                           wk.astype(f32), precision=HIGHEST)  # [B,H,kvr]
+        s_lat = jnp.einsum("bhk,bsk->bhs", q_lat.astype(cdt), c_cache,
+                           preferred_element_type=f32)
+        s_rope = jnp.einsum("bhr,bsr->bhs", q_rope[:, 0].astype(cdt),
+                            r_cache, preferred_element_type=f32)
+        scores = (s_lat + s_rope) / jnp.sqrt(f32(hd + r))
+        valid = jnp.arange(c_cache.shape[1])[None, None, :] <= cache_len
+        w = jax.nn.softmax(jnp.where(valid, scores, -jnp.inf), axis=-1)
+        ctx = jnp.einsum("bhs,bsk->bhk", w.astype(cdt), c_cache,
+                         preferred_element_type=f32)          # [B,H,kvr]
         wv = params["wv_b"].reshape(kvr, cfg.n_heads, hd)
-        out = jnp.einsum("bhk,khd->bhd", ctx_lat, wv.astype(jnp.float32))
-    else:
-        k_nope = jnp.einsum("bsk,kD->bsD", c_cache.astype(jnp.float32),
-                            params["wk_b"].astype(jnp.float32)).reshape(
-            b, s_max, cfg.n_heads, hd)
-        v_full = jnp.einsum("bsk,kD->bsD", c_cache.astype(jnp.float32),
-                            params["wv_b"].astype(jnp.float32)).reshape(
-            b, s_max, cfg.n_heads, hd)
-        s_nope = jnp.einsum("bhd,bshd->bhs", q_nope_h.astype(jnp.float32),
-                            k_nope)
-        s_rope = jnp.einsum("bhr,bsr->bhs", q_rope_h.astype(jnp.float32),
-                            r_cache.astype(jnp.float32))
-        scores = (s_nope + s_rope) * scale
-        scores = jnp.where(valid, scores, -jnp.inf)
-        w = jax.nn.softmax(scores, axis=-1)
-        out = jnp.einsum("bhs,bshd->bhd", w, v_full)
-
+        out = jnp.einsum("bhk,khd->bhd", ctx, wv.astype(f32),
+                         precision=HIGHEST)
     out = out.reshape(b, 1, cfg.n_heads * hd).astype(x.dtype)
-    return out @ params["wo"].astype(x.dtype), (c_cache, r_cache)
+    (out,) = matmul(out, params["wo"])
+    return out, (c_cache, r_cache)

@@ -130,6 +130,14 @@ def _shared_block_init(key, arch: ArchConfig) -> Params:
     return p
 
 
+def _residual(arch: ArchConfig, h: jax.Array, branch: jax.Array) -> jax.Array:
+    """``h`` plus a residual branch scaled by ``arch.residual_scale``; the
+    scale is applied only where it is not 1, so the default adds no op."""
+    if arch.residual_scale != 1.0:
+        branch = branch * jnp.asarray(arch.residual_scale, branch.dtype)
+    return h + branch
+
+
 def _layer_apply_full(p: Params, arch: ArchConfig, h: jax.Array,
                       rt: RuntimeConfig) -> tuple[jax.Array, jax.Array]:
     """Full-sequence decoder layer (train / prefill w/o cache).
@@ -143,23 +151,24 @@ def _layer_apply_full(p: Params, arch: ArchConfig, h: jax.Array,
     if arch.family in ("ssm", "hybrid"):
         with jax.named_scope("mlp"):
             x = constrain(rms_norm(h, p["ln"]["scale"]), "tp_in", rt)
-            h = h + constrain(mamba2_apply(p["mamba"], ssm_config(arch), x),
-                              "hidden", rt)
+            h = _residual(arch, h, constrain(
+                mamba2_apply(p["mamba"], ssm_config(arch), x), "hidden", rt))
         return constrain(h, "hidden", rt), aux
     acfg = attn_config(arch)
     with jax.named_scope("attn"):
         x = constrain(rms_norm(h, p["ln"]["scale"]), "tp_in", rt)
         attn = mla_apply if arch.attn_type == "mla" else gqa_apply
-        h = h + constrain(attn(p["attn"], acfg, x), "hidden", rt)
+        h = _residual(arch, h, constrain(attn(p["attn"], acfg, x), "hidden",
+                                         rt))
     with jax.named_scope("mlp"):
         x2 = constrain(rms_norm(h, p["ln2"]["scale"]), "tp_in", rt)
         if arch.family == "moe":
-            h = h + constrain(moe_apply(p["moe"], moe_config(arch), x2),
-                              "hidden", rt)
+            h = _residual(arch, h, constrain(
+                moe_apply(p["moe"], moe_config(arch), x2), "hidden", rt))
             aux = aux_load_balance_loss(p["moe"], moe_config(arch), x2)
         else:
-            h = h + constrain(mlp_apply(p["mlp"], x2, arch.act), "hidden",
-                              rt)
+            h = _residual(arch, h, constrain(mlp_apply(p["mlp"], x2, arch.act),
+                                             "hidden", rt))
     return constrain(h, "hidden", rt), aux
 
 
@@ -229,8 +238,9 @@ def _cast_blocks(blocks: Params, dtype) -> Params:
     The layers cast each matrix to the compute dtype where they use it,
     so casting the stack and then slicing gives the same bits as slicing
     and then casting.  Training and prefill cast every stack here; the
-    GQA decode step only what its weight-streaming matmul does not read
-    (MoE experts, cross attention): it writes no copy of the rest.
+    decode step only what its weight-streaming matmul does not read (MoE
+    experts, cross attention; not MLA's W_UK and W_UV, used in float32):
+    it writes no copy of the rest.
 
     The barrier changes no value and adds no op.  Where the layers want
     a stack in another layout, XLA folds the layout change and the cast
@@ -305,7 +315,7 @@ def _cross_decoder_forward(params: Params, arch: ArchConfig, h: jax.Array,
     def one_layer(hh, bp):
         with jax.named_scope("attn"):
             x = rms_norm(hh, bp["ln"]["scale"])
-            hh = hh + gqa_apply(bp["attn"], acfg, x)
+            hh = _residual(arch, hh, gqa_apply(bp["attn"], acfg, x))
             xc = rms_norm(hh, bp["ln_cross"]["scale"])
             # cross attention: q from decoder, k/v from encoder output
             b, s, _ = xc.shape
@@ -321,10 +331,10 @@ def _cross_decoder_forward(params: Params, arch: ArchConfig, h: jax.Array,
             o = flash_attention(jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2),
                                 jnp.swapaxes(v, 1, 2), causal=False)
             o = jnp.swapaxes(o, 1, 2).reshape(b, s, xcfg.n_heads * hd)
-            hh = hh + o @ bp["cross"]["wo"].astype(xc.dtype)
+            hh = _residual(arch, hh, o @ bp["cross"]["wo"].astype(xc.dtype))
         with jax.named_scope("mlp"):
             x2 = rms_norm(hh, bp["ln2"]["scale"])
-            hh = hh + mlp_apply(bp["mlp"], x2, arch.act)
+            hh = _residual(arch, hh, mlp_apply(bp["mlp"], x2, arch.act))
         return constrain(hh, "hidden", rt), jnp.zeros((), jnp.float32)
 
     layer = one_layer
@@ -340,18 +350,22 @@ def _cross_decoder_forward(params: Params, arch: ArchConfig, h: jax.Array,
 def embed_tokens(params: Params, arch: ArchConfig, tokens: jax.Array,
                  rt: RuntimeConfig, compute_dtype) -> jax.Array:
     e = jnp.take(params["embed"], tokens, axis=0).astype(compute_dtype)
-    return constrain(e * jnp.sqrt(arch.d_model).astype(compute_dtype),
-                     "hidden", rt)
+    scale = (jnp.asarray(arch.embed_scale, compute_dtype) if arch.embed_scale
+             else jnp.sqrt(arch.d_model).astype(compute_dtype))
+    return constrain(e * scale, "hidden", rt)
 
 
 @jax.named_scope("head")
-def _head(params: Params, h: jax.Array, compute_dtype,
+def _head(params: Params, arch: ArchConfig, h: jax.Array, compute_dtype,
           last_only: bool = False) -> jax.Array:
-    """Final norm and logits, of the last position alone with
-    ``last_only``; the head is the embedding's transpose when tied."""
+    """Final norm, division by ``arch.head_divisor`` (where it is not 1)
+    and logits, of the last position alone with ``last_only``; the head
+    is the embedding's transpose when tied."""
     h = rms_norm(h, params["final_norm"]["scale"])
     if last_only:
         h = h[:, -1:, :]
+    if arch.head_divisor != 1.0:
+        h = h / jnp.asarray(arch.head_divisor, h.dtype)
     head = params.get("head", None)
     w = (params["embed"].T if head is None else head).astype(compute_dtype)
     return h @ w
@@ -383,7 +397,7 @@ def forward(params: Params, arch: ArchConfig, batch: dict[str, jax.Array],
     else:
         h, aux = _scan_layers(params, arch, h, rt)
 
-    logits = _head(params, h, cd)
+    logits = _head(params, arch, h, cd)
     return constrain(logits, "logits", rt), aux
 
 
@@ -480,8 +494,8 @@ def _ffn(bp: Params, arch: ArchConfig, h: jax.Array,
     with jax.named_scope("mlp"):
         x = rms_norm(h, bp["ln2"]["scale"])
         if arch.family == "moe":
-            return h + moe_apply(bp["moe"], moe_config(arch), x)
-        return h + mlp_apply(bp["mlp"], x, arch.act, matmul)
+            return _residual(arch, h, moe_apply(bp["moe"], moe_config(arch), x))
+        return _residual(arch, h, mlp_apply(bp["mlp"], x, arch.act, matmul))
 
 
 def _split_stacks(blocks: Params, names: tuple[str, ...]
@@ -525,54 +539,50 @@ def decode_step(params: Params, arch: ArchConfig, cache: Params,
     emb0 = h
 
     if arch.family in ("dense", "moe", "vlm") or arch.is_encdec:
-        if arch.attn_type == "mla":
-            xs = (params["blocks"], cache["c_kv"], cache["k_rope"])
+        mla = arch.attn_type == "mla"
+        keys = ("c_kv", "k_rope") if mla else ("k", "v")
+        # attention's and the dense MLP's matrices stay whole float32
+        # stacks, closed over by the scan (scanning them would slice
+        # each layer out), and are read by the layer's matmul
+        # (_stack_matmul); MLA's up-projections, which the absorbed
+        # attention applies per head, are sliced per layer and stay
+        # float32; the rest that is cast (MoE experts, cross attention)
+        # is cast once, under its scope, where XLA would otherwise hoist
+        # each layer's cast out of the scan with no name
+        mats, rest = _split_stacks(params["blocks"], ("attn", "mlp"))
+        per_head = {k: mats["attn"].pop(k) for k in ("wk_b", "wv_b") if mla}
+        # GQA's cache is scanned; MLA's stacks are closed over, and the
+        # latent attention reads its layer's slice itself, so that its
+        # scope (`latent`) holds all of its reading of the cache
+        stacks = (cache[keys[0]], cache[keys[1]])
+        xs = (_cast_blocks(rest, cd), per_head, jnp.arange(arch.n_layers),
+              () if mla else stacks)
+        if arch.is_encdec:
+            xs += (cache["cross_k"], cache["cross_v"])
 
-            def layer(carry, x):
-                hh = carry
-                bp, ck, kr = x
-                with jax.named_scope("attn"):
-                    xn = rms_norm(hh, bp["ln"]["scale"])
-                    o, (ck, kr) = mla_decode(bp["attn"], acfg, xn, (ck, kr),
-                                             pos, absorb=rt.mla_absorb)
-                    hh = hh + o
-                return _ffn(bp, arch, hh), (ck, kr)
+        def layer(carry, x):
+            hh = carry
+            bp, ph, idx, kv = x[:4]
+            bp = {**bp, **{k: {**bp[k], **m} for k, m in mats.items()}}
+            bp["attn"] = {**bp["attn"], **ph}
+            matmul = _stack_matmul(idx)
+            with jax.named_scope("attn"):
+                xn = rms_norm(hh, bp["ln"]["scale"])
+                if mla:
+                    o, kv = mla_decode(bp["attn"], acfg, xn, stacks, pos,
+                                       matmul, layer=idx)
+                else:
+                    o, kv = gqa_decode(bp["attn"], acfg, xn, kv, pos, matmul)
+                hh = _residual(arch, hh, o)
+                if arch.is_encdec:
+                    xc = rms_norm(hh, bp["ln_cross"]["scale"])
+                    hh = _residual(arch, hh, _cross_attn_decode(
+                        bp, arch, xc[:, 0], *x[4:]))
+            return _ffn(bp, arch, hh, matmul), kv
 
-            with jax.named_scope("layers"):
-                h, (ckv, krope) = jax.lax.scan(layer, h, xs)
-            cache = {**cache, "c_kv": ckv, "k_rope": krope}
-        else:
-            # attention's and the dense MLP's matrices stay whole float32
-            # stacks, closed over by the scan (scanning them would slice
-            # each layer out), and are read by the layer's matmul
-            # (_stack_matmul); the rest that is cast (MoE experts, cross
-            # attention) is cast once, under its scope, where XLA would
-            # otherwise hoist each layer's cast out of the scan with no name
-            mats, rest = _split_stacks(params["blocks"], ("attn", "mlp"))
-            xs = (_cast_blocks(rest, cd), jnp.arange(arch.n_layers),
-                  cache["k"], cache["v"])
-            if arch.is_encdec:
-                xs += (cache["cross_k"], cache["cross_v"])
-
-            def layer(carry, x):
-                hh = carry
-                bp, idx, kc, vc = x[:4]
-                bp = {**bp, **{k: {**bp[k], **m} for k, m in mats.items()}}
-                matmul = _stack_matmul(idx)
-                with jax.named_scope("attn"):
-                    xn = rms_norm(hh, bp["ln"]["scale"])
-                    o, (kc, vc) = gqa_decode(bp["attn"], acfg, xn, (kc, vc),
-                                             pos, matmul)
-                    hh = hh + o
-                    if arch.is_encdec:
-                        xc = rms_norm(hh, bp["ln_cross"]["scale"])
-                        hh = hh + _cross_attn_decode(bp, arch, xc[:, 0],
-                                                     *x[4:])
-                return _ffn(bp, arch, hh, matmul), (kc, vc)
-
-            with jax.named_scope("layers"):
-                h, (kc, vc) = jax.lax.scan(layer, h, xs)
-            cache = {**cache, "k": kc, "v": vc}
+        with jax.named_scope("layers"):
+            h, (c0, c1) = jax.lax.scan(layer, h, xs)
+        cache = {**cache, keys[0]: c0, keys[1]: c1}
     else:  # ssm / hybrid
         scfg = ssm_config(arch)
         every = arch.shared_attn_every
@@ -585,7 +595,7 @@ def decode_step(params: Params, arch: ArchConfig, cache: Params,
             with jax.named_scope("mlp"):
                 xn = rms_norm(hh, bp["ln"]["scale"])
                 o, (hc, cc) = mamba2_decode(bp["mamba"], scfg, xn, (hc, cc))
-                hh = hh + o
+                hh = _residual(arch, hh, o)
 
             if arch.family == "hybrid" and every:
                 u = idx // every
@@ -624,7 +634,7 @@ def decode_step(params: Params, arch: ArchConfig, cache: Params,
         if arch.family == "hybrid" and every:
             cache = {**cache, "shared_k": sk, "shared_v": sv}
 
-    logits = _head(params, h, cd)
+    logits = _head(params, arch, h, cd)
     cache = {**cache, "len": cache["len"] + 1}
     return constrain(logits, "logits", rt), cache
 
@@ -659,7 +669,7 @@ def prefill(params: Params, arch: ArchConfig, batch: dict[str, jax.Array],
             with jax.named_scope("attn"):
                 xn = rms_norm(hh, bp["ln"]["scale"])
                 o, (kc, vc) = gqa_prefill(bp["attn"], acfg, xn)
-                hh = hh + o
+                hh = _residual(arch, hh, o)
                 xc = rms_norm(hh, bp["ln_cross"]["scale"])
                 be, se, _ = enc_out.shape
                 q = (xc @ bp["cross"]["wq"].astype(cd)).reshape(
@@ -672,7 +682,7 @@ def prefill(params: Params, arch: ArchConfig, batch: dict[str, jax.Array],
                                      jnp.swapaxes(xk, 1, 2),
                                      jnp.swapaxes(xv, 1, 2), causal=False)
                 o2 = o2.swapaxes(1, 2).reshape(be, -1, xcfg.n_heads * hd)
-                hh = hh + o2 @ bp["cross"]["wo"].astype(cd)
+                hh = _residual(arch, hh, o2 @ bp["cross"]["wo"].astype(cd))
             hh = _ffn(bp, arch, hh)
             return constrain(hh, "hidden", rt), (
                 kc, vc, jnp.swapaxes(xk, 1, 2), jnp.swapaxes(xv, 1, 2))
@@ -686,39 +696,31 @@ def prefill(params: Params, arch: ArchConfig, batch: dict[str, jax.Array],
             cache["cross_k"] = xk[:, :, :, :s_enc].astype(cd)
             cache["cross_v"] = xv[:, :, :, :s_enc].astype(cd)
     elif arch.family in ("dense", "moe", "vlm"):
-        if arch.attn_type == "mla":
-            def layer(hh, bp):
-                with jax.named_scope("attn"):
-                    xn = rms_norm(hh, bp["ln"]["scale"])
-                    o, (ckv, kr) = mla_prefill(bp["attn"], acfg, xn)
-                    hh = hh + o
-                return constrain(_ffn(bp, arch, hh), "hidden", rt), (ckv, kr)
+        mla = arch.attn_type == "mla"
+        attend = mla_prefill if mla else gqa_prefill
+        keys = ("c_kv", "k_rope") if mla else ("k", "v")
 
-            with jax.named_scope("layers"):
-                h, (ckv, kr) = jax.lax.scan(layer, h, blocks)
-                pad = ((0, 0), (0, 0), (0, cache_len - s), (0, 0))
-                cache["c_kv"] = jnp.pad(ckv.astype(cd), pad)
-                cache["k_rope"] = jnp.pad(kr.astype(cd), pad)
-        else:
-            def layer(hh, bp):
-                with jax.named_scope("attn"):
-                    xn = rms_norm(hh, bp["ln"]["scale"])
-                    o, (kc, vc) = gqa_prefill(bp["attn"], acfg, xn)
-                    hh = hh + o
-                return constrain(_ffn(bp, arch, hh), "hidden", rt), (kc, vc)
+        def layer(hh, bp):
+            with jax.named_scope("attn"):
+                xn = rms_norm(hh, bp["ln"]["scale"])
+                o, (c0, c1) = attend(bp["attn"], acfg, xn)
+                hh = _residual(arch, hh, o)
+            return constrain(_ffn(bp, arch, hh), "hidden", rt), (c0, c1)
 
-            with jax.named_scope("layers"):
-                h, (kc, vc) = jax.lax.scan(layer, h, blocks)
-                pad = ((0, 0), (0, 0), (0, 0), (0, cache_len - s), (0, 0))
-                cache["k"] = jnp.pad(kc.astype(cd), pad)
-                cache["v"] = jnp.pad(vc.astype(cd), pad)
+        with jax.named_scope("layers"):
+            h, (c0, c1) = jax.lax.scan(layer, h, blocks)
+            for key, c in zip(keys, (c0, c1)):
+                # [L, B, (heads,) S, D]: pad the positions to cache_len
+                pad = [(0, 0)] * c.ndim
+                pad[-2] = (0, cache_len - s)
+                cache[key] = jnp.pad(c.astype(cd), pad)
     elif arch.family in ("ssm", "hybrid"):
         def layer(hh, bp):
             with jax.named_scope("mlp"):
                 xn = rms_norm(hh, bp["ln"]["scale"])
                 o, (hf, conv_tail) = mamba2_apply(
                     bp["mamba"], ssm_config(arch), xn, return_state=True)
-                hh = hh + o
+                hh = _residual(arch, hh, o)
             return constrain(hh, "hidden", rt), (hf, conv_tail)
 
         # Note: prefill for hybrid ignores the shared attention block's
@@ -728,6 +730,6 @@ def prefill(params: Params, arch: ArchConfig, batch: dict[str, jax.Array],
             h, (hf, conv_tail) = jax.lax.scan(layer, h, blocks)
         cache["ssm_h"] = hf
         cache["ssm_conv"] = conv_tail.astype(cd)
-    logits = _head(params, h, cd, last_only=True)
+    logits = _head(params, arch, h, cd, last_only=True)
     cache = {**cache, "len": jnp.asarray(s, jnp.int32)}
     return logits, cache

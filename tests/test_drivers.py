@@ -66,10 +66,15 @@ def test_serve_driver_decodes():
 
 
 def test_serve_driver_mla_absorb():
+    """minicpm3-4b's tiny serve run decodes in the absorbed latent form,
+    the one MLA decode path (no flag selects it)."""
     from repro.launch.serve import main
     out = main(["--arch", "minicpm3-4b", "--preset", "tiny", "--batch", "2",
-                "--prompt-len", "16", "--gen", "4", "--mla-absorb"])
+                "--prompt-len", "16", "--gen", "4"])
     assert out["generated"].shape == (2, 4)
+    assert out["tok_per_s"] > 0
+    with pytest.raises(SystemExit):
+        main(["--arch", "minicpm3-4b", "--mla-absorb"])
 
 
 def test_serve_driver_ssm():

@@ -11,9 +11,9 @@ from repro.configs import ARCH_NAMES, get_arch, tiny_variant
 from repro.configs.base import RuntimeConfig
 from repro.models import (decode_step, forward, init_model, loss_fn,
                           make_cache, prefill)
-from repro.models.attention import (AttnConfig, flash_attention, gqa_apply,
-                                    gqa_init, mla_decode, mla_init,
-                                    mla_prefill)
+from repro.models.attention import (AttnConfig, _mla_qkv_full,
+                                    flash_attention, gqa_apply, gqa_init,
+                                    mla_decode, mla_init, mla_prefill)
 from repro.models.ssm import ssd_chunked, ssd_reference
 
 RT = RuntimeConfig(remat="none")
@@ -87,6 +87,32 @@ def test_flash_attention_matches_naive():
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
 
 
+def test_flash_attention_kv_block_shrinks_to_fit_its_scores(monkeypatch):
+    """A query whose scores over one KV block would pass the budget
+    halves the block, here from 512 to its floor of 128 keys: the same
+    attention, in three blocks where there was one."""
+    from repro.models import attention
+
+    rng = np.random.default_rng(5)
+    b, h, s, d = 1, 2, 300, 8
+    q, k, v = (jnp.asarray(rng.standard_normal((b, h, s, d)), jnp.float32)
+               for _ in range(3))
+    want = flash_attention(q, k, v, causal=True, block_kv=512)
+    monkeypatch.setattr(attention, "SCORE_BLOCK_BYTES", b * h * s * 64 * 4)
+    monkeypatch.setattr(attention.jax.lax, "scan", _counting_scan(
+        attention.jax.lax.scan, seen := []))
+    got = flash_attention(q, k, v, causal=True, block_kv=512)
+    assert seen == [3]
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+
+
+def _counting_scan(scan, seen):
+    def counted(f, init, xs, *args, **kwargs):
+        seen.append(jax.tree.leaves(xs)[0].shape[0])
+        return scan(f, init, xs, *args, **kwargs)
+    return counted
+
+
 def test_gqa_kv_replication_equivalence():
     """kv_repeat must not change the math (Megatron kv replication)."""
     cfg1 = AttnConfig(d_model=32, n_heads=4, n_kv_heads=2, head_dim=8)
@@ -100,8 +126,10 @@ def test_gqa_kv_replication_equivalence():
 
 
 def test_mla_absorb_equivalence():
-    """Absorbed (latent-space) decode == expanded decode (the §Perf
-    optimization must be exact)."""
+    """The decode's absorbed (latent-space) attention equals the
+    expanded attention, written out here: the latent cache up-projected
+    to per-head K and V, scored with the rope key, softmax over the
+    live positions, then the output projection."""
     cfg = AttnConfig(d_model=32, n_heads=4, n_kv_heads=4, head_dim=8,
                      attn_type="mla", q_lora_rank=16, kv_lora_rank=8,
                      rope_head_dim=4)
@@ -113,9 +141,20 @@ def test_mla_absorb_equivalence():
     cache = (jnp.pad(c_kv, ((0, 0), (0, pad), (0, 0))),
              jnp.pad(k_rope, ((0, 0), (0, pad), (0, 0))))
     x_new = jnp.asarray(rng.standard_normal((2, 1, 32)), jnp.float32)
-    o1, _ = mla_decode(params, cfg, x_new, cache, jnp.int32(6), absorb=False)
-    o2, _ = mla_decode(params, cfg, x_new, cache, jnp.int32(6), absorb=True)
-    np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), atol=1e-4)
+    got, (c, r) = mla_decode(params, cfg, x_new, cache, jnp.int32(6))
+
+    h, hd, rd = cfg.n_heads, cfg.head_dim, cfg.rope_head_dim
+    q_nope, q_rope, c_new, _ = _mla_qkv_full(params, cfg, x_new,
+                                             jnp.full((1,), 6))
+    np.testing.assert_allclose(np.asarray(c[:, 6]), np.asarray(c_new[:, 0]))
+    k = (c[:, :7] @ params["wk_b"]).reshape(2, 7, h, hd)
+    v = (c[:, :7] @ params["wv_b"]).reshape(2, 7, h, hd)
+    scores = (jnp.einsum("bhd,bshd->bhs", q_nope[:, 0], k)
+              + jnp.einsum("bhr,bsr->bhs", q_rope[:, 0], r[:, :7])
+              ) / np.sqrt(hd + rd)
+    o = jnp.einsum("bhs,bshd->bhd", jax.nn.softmax(scores, -1), v)
+    want = o.reshape(2, 1, h * hd) @ params["wo"]
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-4)
 
 
 def test_ssd_chunked_matches_reference():

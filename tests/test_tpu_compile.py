@@ -8,8 +8,12 @@ whether a real-width step program compiles at all:
   * the four Pallas kernels at real widths (qwen3-1.7b decode geometry,
     mamba2-130m chunk geometry, qwen3's embedding table, qwen3's layer
     matrices), each lowered with Mosaic (``tpu_custom_call`` in the HLO);
-  * the qwen3-1.7b full-width decode step on one chip, whose layer
-    matrices are all read by the weight-streaming kernel.
+  * the weight-streaming kernel at minicpm3-4b's widths;
+  * the qwen3-1.7b full-width decode step on one chip, and minicpm3-4b's
+    at its published widths and the benchmark's 31 layers, whose layer
+    matrices are read by the weight-streaming kernel (all of qwen3's;
+    all of minicpm3's but the two that its latent attention applies per
+    head), with no bfloat16 copy of a stack.
 
 The topology is described inside a module fixture (only the pytest
 worker that runs this file loads the TPU compiler), and the persistent
@@ -98,6 +102,11 @@ def test_amm_gather_compiles_with_mosaic(one_chip):
     (2048, (6144, 6144)),           # gate, up
     (6144, (2048,)),                # down
     (14336, (4096,)),               # llama-3-8b's down: K-tiled
+    (2560, (768, 288)),             # minicpm3-4b's q_a and kv_a: K-tiled
+    (768, (3840,)),                 # minicpm3-4b's q_b
+    (2560, (2560,)),                # minicpm3-4b's o
+    (2560, (6400, 6400)),           # minicpm3-4b's gate, up
+    (6400, (2560,)),                # minicpm3-4b's down
 ])
 def test_weight_stream_compiles_with_mosaic(one_chip, k, widths):
     from repro.kernels import weight_stream
@@ -110,13 +119,15 @@ def test_weight_stream_compiles_with_mosaic(one_chip, k, widths):
     assert c.memory_analysis().temp_size_in_bytes < 2**20
 
 
-def test_qwen3_decode_step_compiles_full_width(one_chip, monkeypatch):
-    from repro.configs import get_arch
+def _decode_step_streams_every_stack(one_chip, monkeypatch, arch, seq_len,
+                                     streamed):
+    """Compile ``arch``'s decode step for one described v5e and check
+    that every float32 layer stack named in ``streamed`` is read whole by
+    a kernel and that no stack is cast to bfloat16."""
     from repro.configs.base import RuntimeConfig
     from repro.launch.steps import make_decode_step
     from repro.models import DTypePolicy, init_model, make_cache
 
-    arch = get_arch("qwen3-1.7b")
     policy = DTypePolicy.standard()
 
     def place(tree):
@@ -124,7 +135,8 @@ def test_qwen3_decode_step_compiles_full_width(one_chip, monkeypatch):
 
     params = place(jax.eval_shape(
         lambda k: init_model(k, arch, policy), jax.random.PRNGKey(0)))
-    cache = place(jax.eval_shape(lambda: make_cache(arch, 1152, 8, policy)))
+    cache = place(jax.eval_shape(lambda: make_cache(arch, seq_len, 8,
+                                                    policy)))
     # the model asks the backend for its kernel mode, and sees the CPU
     monkeypatch.setenv("REPRO_KERNEL_MODE", "pallas")
     c = _compile(make_decode_step(arch, RuntimeConfig(remat="none"), policy),
@@ -135,7 +147,8 @@ def test_qwen3_decode_step_compiles_full_width(one_chip, monkeypatch):
     # every layer matrix is read as its f32 stack by a kernel, and no
     # stack is cast: the program holds no bf16 copy of the stacks (2.8 GB)
     hlo = c.as_text()
-    stacks = [x for x in jax.tree.leaves(params["blocks"]) if x.ndim == 3]
+    blocks = params["blocks"]
+    stacks = [x for x in jax.tree.leaves(blocks) if x.ndim == 3]
     shape = lambda x: f"[{','.join(map(str, x.shape))}]"     # noqa: E731
     assert not [ln for ln in hlo.splitlines()
                 if any(f"= bf16{shape(x)}" in ln and " convert(" in ln
@@ -143,6 +156,36 @@ def test_qwen3_decode_step_compiles_full_width(one_chip, monkeypatch):
     read = Counter(m for ln in hlo.splitlines()
                    if 'custom_call_target="tpu_custom_call"' in ln
                    for m in re.findall(r"f32(\[\d+,\d+,\d+\])", ln))
-    assert read == Counter(shape(x) for x in stacks)
+    assert read == Counter(shape(blocks[g][n]) for g, n in streamed)
     bf16_stacks = sum(x.size * 2 for x in stacks)
     assert mem.temp_size_in_bytes < bf16_stacks
+    return mem
+
+
+def test_qwen3_decode_step_compiles_full_width(one_chip, monkeypatch):
+    from repro.configs import get_arch
+
+    streamed = [("attn", n) for n in ("wq", "wk", "wv", "wo")] + [
+        ("mlp", n) for n in ("w_gate", "w_up", "w_down")]
+    _decode_step_streams_every_stack(one_chip, monkeypatch,
+                                     get_arch("qwen3-1.7b"), 1152, streamed)
+
+
+def test_minicpm3_decode_step_compiles_full_width(one_chip, monkeypatch):
+    """minicpm3-4b at its published widths, cut to the benchmark's 31
+    layers, with the benchmark cell's batch and cache: the MLA and MLP
+    stacks are each read whole by the kernel; W_UK and W_UV, which the
+    latent attention applies per head, are sliced per layer in float32
+    and cast as no stack; and the step fits one chip."""
+    import dataclasses
+
+    from repro.configs import get_arch
+
+    arch = dataclasses.replace(get_arch("minicpm3-4b"), n_layers=31)
+    streamed = [("attn", n) for n in ("wq_a", "wkv_a", "wq_b", "wo")] + [
+        ("mlp", n) for n in ("w_gate", "w_up", "w_down")]
+    mem = _decode_step_streams_every_stack(one_chip, monkeypatch, arch,
+                                           16896, streamed)
+    held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes)
+    assert held < 16 * 2**30, f"decode step holds {held / 2**30:.1f} GiB"

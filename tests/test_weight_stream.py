@@ -2,8 +2,10 @@
 ``x @ W[l].astype(bf16)`` with float32 accumulation, in the Pallas
 interpreter and on the XLA grid path: batches of 1 to 64 rows, tiny
 widths (every layer of a small stack), qwen3-1.7b's (2048 x 6144,
-6144 x 2048, 2048 x 1024, and q, k, v in one call), and a K too deep for
-one block column (llama-3-8b's down projection, 14336), which is tiled.  Mosaic's compile of it is in ``test_tpu_compile.py``."""
+6144 x 2048, 2048 x 1024, and q, k, v in one call), minicpm3-4b's q_a
+with kv_a (2560 x (768 + 288), tiled in K) and q_b (768 x 3840), and a K
+too deep for one block column (llama-3-8b's down projection, 14336),
+which is tiled.  Mosaic's compile of it is in ``test_tpu_compile.py``."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -23,6 +25,9 @@ SHAPES = {
     "qwen3.k": (3, 2048, (1024,), 2),
     "qwen3.qkv": (1, 2048, (2048, 1024, 1024), 2),
     "deep.k_tiled": (8, 14336, (128, 256), 2),
+    # minicpm3-4b: q_a with kv_a (288 columns, K-tiled in f32), and q_b
+    "minicpm3.q_a_kv_a.k_tiled": (8, 2560, (768, 288), 2),
+    "minicpm3.q_b": (8, 768, (3840,), 2),
 }
 CASES = [(name, layer) for name, (_, _, _, n_layers) in SHAPES.items()
          for layer in range(n_layers)
