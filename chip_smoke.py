@@ -12,7 +12,8 @@ not reach a chip this one holds):
             from seed 0).  Generated ids must be in the vocabulary and
             every logit finite; the prefill's last-position logits of
             the first prompts are compared with a float32 reference
-            (float32 compute, "highest" matmul precision) on this chip.
+            (float32 compute, "highest" matmul precision, attention by
+            the XLA scan rather than the prefill kernel) on this chip.
   explorer  the device engines: ``schedule_batched`` over every golden
             design row of every bench (tests/golden_schedule.json),
             ``run_sweep(backend="jax", jobs=1)`` against the C loop on
@@ -84,19 +85,27 @@ def _rel_l2(got, want) -> tuple[float, float]:
 # ---------------------------------------------------------------- serve
 def _reference_prefill(arch, tokens, cache_len: int):
     """float32 prefill logits of ``tokens`` with the serve entry point's
-    seed-0 weights."""
+    seed-0 weights.  Traced under a one-device activation sharder, so
+    its attention is the XLA scan and not the prefill kernel that the
+    served program runs: a fault in the kernel cannot show on both
+    sides of the check."""
     import jax
     import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import Mesh
 
     from repro.configs.base import RuntimeConfig
+    from repro.launch import sharding as shd
     from repro.launch.steps import make_prefill_step
     from repro.models import DTypePolicy, init_model
 
     f32 = DTypePolicy(jnp.float32, jnp.float32, jnp.float32)
     params = init_model(jax.random.PRNGKey(0), arch, DTypePolicy.standard())
-    step = jax.jit(make_prefill_step(arch, RuntimeConfig(remat="none"), f32,
-                                     cache_len))
-    with jax.default_matmul_precision("highest"):
+    mesh = Mesh(np.array(jax.devices()[:1]).reshape(1, 1), ("data", "model"))
+    with shd.activation_sharding(mesh, ("data",)), \
+            jax.default_matmul_precision("highest"):
+        step = jax.jit(make_prefill_step(arch, RuntimeConfig(remat="none"),
+                                         f32, cache_len))
         logits, _ = step(params, {"tokens": tokens})
     return jax.device_get(logits[:, -1, :])
 
@@ -311,14 +320,15 @@ def phase_sharded(preset: str = "full", batch: int = SERVE["batch"],
     baxes = shd.batch_axes_for(mesh, batch)
     tok_sh = NamedSharding(mesh, P(baxes, None))
     with shd.activation_sharding(mesh, baxes, False, "tp"):
-        logits4, cache4 = jax.jit(prefill)(
+        # step functions of their own, traced under the mesh: JAX reuses
+        # a traced program whatever context it is called in, and the
+        # one-device traces hold the prefill attention and
+        # weight-streaming kernels, which XLA cannot partition
+        prefill4 = jax.jit(make_prefill_step(arch, rt, policy, cache_len))
+        logits4, cache4 = prefill4(
             sparams, {"tokens": jax.device_put(tokens, tok_sh)})
         cache_sh = shd.to_named(shd.cache_pspecs(cache4, mesh, batch), mesh)
         cache4 = jax.device_put(cache4, cache_sh)
-        # a step function of its own, traced under the mesh: JAX reuses a
-        # traced program whatever context it is called in, and the
-        # one-device trace holds the weight-streaming kernel, which XLA
-        # cannot partition
         dec4 = jax.jit(make_decode_step(arch, rt, policy),
                        in_shardings=(param_sh, cache_sh, tok_sh))
         got = [np.asarray(logits4[:, -1, :], np.float32)]

@@ -43,6 +43,12 @@ stand after its input blocks and returns their new values.  A block's
 first visit holds undefined values on Pallas (zeros in ``xla`` mode), so
 the body must overwrite it then — the pattern of a contraction tiled
 along its reduced axis.
+
+A program may keep *scratch* across grid steps (``scratch_shapes``):
+on-chip buffers (VMEM on the TPU, a loop carry in ``xla`` mode) that
+the block function receives after its blocks and returns, new, after
+its outputs.  Scratch holds undefined values until the body first
+writes it (zeros in ``xla`` mode).
 """
 from __future__ import annotations
 
@@ -127,17 +133,21 @@ def _block_starts(spec: Spec, coords: Sequence[jax.Array],
 def _xla_call(block_fn: Callable, grid: Sequence[int], in_specs: Sequence[Spec],
               out_specs: Sequence[Spec],
               out_shapes: Sequence[jax.ShapeDtypeStruct], args: Sequence,
-              n_scalar: int, accumulate: bool):
+              n_scalar: int, accumulate: bool,
+              scratch_shapes: Sequence[jax.ShapeDtypeStruct]):
     """Execute the blocked program as pure XLA ops (the interpreter-bypass
     path).  Each grid step slices its input blocks, runs the block
     function, and writes the output blocks back; XLA compiles the loop
     to native code on every backend."""
     steps = math.prod(grid)
     scalars, args = args[:n_scalar], args[n_scalar:]
-    outs0 = [jnp.zeros(s.shape, s.dtype) for s in out_shapes]
+    n_out = len(out_shapes)
+    carry0 = [jnp.zeros(s.shape, s.dtype)
+              for s in (*out_shapes, *scratch_shapes)]
 
-    def one_step(step, outs):
+    def one_step(step, carry):
         coords = _unravel(jnp.asarray(step, jnp.int32), grid)
+        outs, scratch = carry[:n_out], carry[n_out:]
         ins = [lax.dynamic_slice(a, _block_starts(s, coords, scalars),
                                  s.block_shape)
                for a, s in zip(args, in_specs)]
@@ -145,27 +155,31 @@ def _xla_call(block_fn: Callable, grid: Sequence[int], in_specs: Sequence[Spec],
             ins += [lax.dynamic_slice(o, _block_starts(s, coords, scalars),
                                       s.block_shape)
                     for o, s in zip(outs, out_specs)]
-        res = block_fn(coords, *scalars, *ins) if n_scalar else block_fn(*ins)
+        pre = (coords, *scalars) if n_scalar else ()
+        res = block_fn(*pre, *ins, *scratch)
         res = res if isinstance(res, (tuple, list)) else (res,)
         return [lax.dynamic_update_slice(o, v.astype(o.dtype),
                                          _block_starts(s, coords, scalars))
-                for o, v, s in zip(outs, res, out_specs)]
+                for o, v, s in zip(outs, res, out_specs)] + [
+            v.astype(c.dtype) for v, c in zip(res[n_out:], scratch)]
 
     if steps == 1:
-        outs = one_step(0, outs0)
+        carry = one_step(0, carry0)
     else:
-        outs = lax.fori_loop(0, steps, one_step, outs0)
-    return tuple(outs)
+        carry = lax.fori_loop(0, steps, one_step, carry0)
+    return tuple(carry[:n_out])
 
 
-def _pallas_wrap(block_fn: Callable, n_in: int, n_scalar: int,
+def _pallas_wrap(block_fn: Callable, n_in: int, n_out: int, n_scalar: int,
                  n_grid: int, accumulate: bool) -> Callable:
     """Adapt a value->value block function to a Pallas ref kernel:
     whole-block loads, call, whole-block stores.  Scalar-prefetch refs
-    stay refs (SMEM on the TPU) and are indexed by the body."""
+    stay refs (SMEM on the TPU) and are indexed by the body; scratch
+    refs follow the output refs."""
     def kernel(*refs):
         scalars, refs = refs[:n_scalar], refs[n_scalar:]
-        ins = [r[...] for r in (refs if accumulate else refs[:n_in])]
+        held = refs[n_in:n_in + n_out] if accumulate else ()
+        ins = [r[...] for r in (*refs[:n_in], *held, *refs[n_in + n_out:])]
         if n_scalar:
             coords = tuple(pl.program_id(a) for a in range(n_grid))
             res = block_fn(coords, *scalars, *ins)
@@ -177,11 +191,14 @@ def _pallas_wrap(block_fn: Callable, n_in: int, n_scalar: int,
     return kernel
 
 
-def _grid_spec(grid, in_specs, out_specs, n_scalar: int):
+def _grid_spec(grid, in_specs, out_specs, n_scalar: int, scratch_shapes):
     kw = dict(grid=grid, in_specs=[s.to_pallas() for s in in_specs],
               out_specs=[s.to_pallas() for s in out_specs])
-    if n_scalar:
-        return pltpu.PrefetchScalarGridSpec(num_scalar_prefetch=n_scalar, **kw)
+    if n_scalar or scratch_shapes:
+        return pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=n_scalar,
+            scratch_shapes=[pltpu.VMEM(s.shape, s.dtype)
+                            for s in scratch_shapes], **kw)
     return pl.GridSpec(**kw)
 
 
@@ -190,7 +207,9 @@ def grid_call(block_fn: Callable, *, grid: Sequence[int],
               out_shapes: Sequence[jax.ShapeDtypeStruct], mode: str,
               num_scalar_prefetch: int = 0,
               unpack: bool | None = None, accumulate: bool = False,
-              name: str | None = None) -> Callable:
+              name: str | None = None,
+              scratch_shapes: Sequence[jax.ShapeDtypeStruct] = ()
+              ) -> Callable:
     """Build the executable for one blocked kernel program.
 
     Returns ``f(*args) -> out`` (single out_shape) or ``-> tuple``.
@@ -198,9 +217,10 @@ def grid_call(block_fn: Callable, *, grid: Sequence[int],
     With ``num_scalar_prefetch = k`` the first k operands are int32
     tables: index maps are called as ``index_map(*coords, *tables)`` and
     the block function as ``block_fn(coords, *tables, *blocks)``.  With
-    ``accumulate`` the output blocks follow the input blocks (see the
-    module's doc).  ``name`` names the Pallas kernel, and with it the
-    device op in a profile.
+    ``accumulate`` the output blocks follow the input blocks, and the
+    ``scratch_shapes`` values follow both (see the module's doc), the
+    new scratch values following the outputs in the result.  ``name``
+    names the Pallas kernel, and with it the device op in a profile.
     """
     grid = tuple(int(g) for g in grid)
     out_shapes = list(out_shapes)
@@ -213,12 +233,14 @@ def grid_call(block_fn: Callable, *, grid: Sequence[int],
                              f"got {len(args)}")
         if mode == "xla":
             outs = _xla_call(block_fn, grid, in_specs, out_specs,
-                             out_shapes, args, n_scalar, accumulate)
+                             out_shapes, args, n_scalar, accumulate,
+                             scratch_shapes)
         elif mode in ("pallas", "interpret"):
             outs = pl.pallas_call(
-                _pallas_wrap(block_fn, len(in_specs), n_scalar, len(grid),
-                             accumulate),
-                grid_spec=_grid_spec(grid, in_specs, out_specs, n_scalar),
+                _pallas_wrap(block_fn, len(in_specs), len(out_shapes),
+                             n_scalar, len(grid), accumulate),
+                grid_spec=_grid_spec(grid, in_specs, out_specs, n_scalar,
+                                     scratch_shapes),
                 out_shape=out_shapes,
                 interpret=(mode == "interpret"),
                 name=name,

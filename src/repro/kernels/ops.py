@@ -5,7 +5,8 @@ Dispatch: every wrapper resolves an execution mode (see
 resolve a block configuration (explicit argument > autotuned table in
 ``_autotune_cache.json`` > kernel default).  ``amm_gather`` serves one
 request per grid step and has no block size to tune; ``weight_stream``
-sizes its blocks from the weights' widths.  The
+sizes its blocks from the weights' widths and ``flash_prefill`` from the
+prompt's length and the query group.  The
 default mode is *compiled* — real ``pallas_call`` lowering on TPU/GPU,
 the XLA grid path on CPU — and runs through a jit'd implementation
 with mode and blocks held static.
@@ -29,6 +30,7 @@ import jax.numpy as jnp
 from repro.kernels import autotune
 from repro.kernels.amm_gather import amm_gather_u32
 from repro.kernels.banked_kv_decode import banked_kv_decode
+from repro.kernels.flash_prefill import flash_prefill as _flash_prefill_call
 from repro.kernels.lowering import resolve_mode
 from repro.kernels.ssd_scan import ssd_chunk_step
 from repro.kernels.weight_stream import weight_stream_matmul
@@ -154,3 +156,17 @@ def weight_stream(x: jax.Array, ws, layer: jax.Array,
     mode = resolve_mode(interpret, mode)
     fn = _weight_stream_impl if mode == "interpret" else _weight_stream
     return fn(x, tuple(ws), layer, mode)
+
+
+_flash_prefill = jax.jit(_flash_prefill_call, static_argnames=("mode",))
+
+
+def flash_prefill(q: jax.Array, k: jax.Array, v: jax.Array,
+                  interpret: bool | None = None, mode: str | None = None
+                  ) -> jax.Array:
+    """Causal prefill attention with each score block kept on-chip and
+    the key blocks past the diagonal skipped (see flash_prefill.py).
+    q: [B, Hq, S, D]; k/v: [B, Hkv, S, D]; returns [B, Hq, S, D]."""
+    mode = resolve_mode(interpret, mode)
+    fn = _flash_prefill_call if mode == "interpret" else _flash_prefill
+    return fn(q, k, v, mode=mode)

@@ -78,6 +78,24 @@ def kv_decode_ref(q: jax.Array, k: jax.Array, v: jax.Array,
     return out.reshape(b, hq, d).astype(q.dtype)
 
 
+def causal_attention_ref(q: jax.Array, k: jax.Array,
+                         v: jax.Array) -> jax.Array:
+    """Dense causal softmax attention in float32 at full precision, the
+    oracle of ``flash_prefill``.  q: [B, Hq, S, D]; k/v: [B, Hkv, S, D]
+    -> [B, Hq, S, D] float32."""
+    b, hq, s, d = q.shape
+    hkv = k.shape[1]
+    hi = jax.lax.Precision.HIGHEST
+    qg = q.reshape(b, hkv, hq // hkv, s, d).astype(jnp.float32)
+    scores = jnp.einsum("bhgqd,bhkd->bhgqk", qg, k.astype(jnp.float32),
+                        precision=hi) / jnp.sqrt(jnp.float32(d))
+    causal = jnp.arange(s)[None, :] <= jnp.arange(s)[:, None]
+    w = jax.nn.softmax(jnp.where(causal, scores, -jnp.inf), axis=-1)
+    out = jnp.einsum("bhgqk,bhkd->bhgqd", w, v.astype(jnp.float32),
+                     precision=hi)
+    return out.reshape(b, hq, s, d)
+
+
 def ssd_chunk_ref(x, dt, cum, B, C, h_in):
     """Same contract as ssd_scan.ssd_chunk_step, dense einsums."""
     la = jnp.where(

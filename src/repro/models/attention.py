@@ -5,8 +5,12 @@ Full-sequence attention is computed *blockwise* over KV chunks with an
 online-softmax accumulator (flash-attention recurrence in pure JAX via
 ``lax.scan``) so the [S, S] score matrix is never materialized — at
 prefill_32k a materialized score tensor would be O(S^2) HBM and the
-dry-run would not fit.  The Pallas TPU kernel in ``repro.kernels`` is
-the hardware-target twin of this reference.
+dry-run would not fit.  Training and the cross attention use this scan
+(it is differentiable).  A prefill's causal self-attention goes through
+``prefill_attention``: the Pallas kernel ``kernels/flash_prefill.py``,
+which keeps each score block on-chip and skips the key blocks past the
+diagonal, unless a launcher partitions the program (XLA cannot
+partition a kernel), where it falls back to the scan.
 
 Decode (single new token against a cached KV of length S) is a separate
 path; with ``kv_seq_shard`` the cache's length axis is sharded over the
@@ -22,6 +26,8 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import flash_prefill
+from repro.launch.sharding import partitioned
 from repro.models.common import (Params, apply_rope, cast_matmul, dense_init,
                                  norm_init, rms_norm)
 
@@ -120,6 +126,16 @@ def flash_attention(q, k, v, *, causal=True, q_offset=0, block_kv=1024):
     return _flash_block_scan(q, k, v, causal, q_offset, block_kv)
 
 
+def prefill_attention(q, k, v, causal: bool, block_kv: int = 1024):
+    """A prefill's self-attention of a prompt to itself (q: [B, Hq, S,
+    D]; k/v: [B, Hkv, S, D]): causal attention takes the Pallas kernel,
+    which has no VJP, unless the program is partitioned over a mesh;
+    anything else takes the scan with key blocks of ``block_kv``."""
+    if causal and not partitioned():
+        return flash_prefill(q, k, v)
+    return _flash_block_scan(q, k, v, causal, 0, block_kv)
+
+
 # ======================================================================
 # GQA
 # ======================================================================
@@ -166,7 +182,7 @@ def _replicate_kv(cfg: AttnConfig, k: jax.Array, v: jax.Array):
 @jax.named_scope("attn")
 def gqa_apply(params: Params, cfg: AttnConfig, x: jax.Array,
               positions: jax.Array | None = None) -> jax.Array:
-    """Full-sequence (train / prefill) GQA."""
+    """Full-sequence GQA (training and scoring; differentiable)."""
     b, s, _ = x.shape
     if positions is None:
         positions = jnp.arange(s)
@@ -189,9 +205,9 @@ def gqa_prefill(params: Params, cfg: AttnConfig, x: jax.Array,
     q, k, v = _project_qkv(params, cfg, x, positions)
     kc, vc = jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2)  # cache: real heads
     kr, vr = _replicate_kv(cfg, k, v)
-    out = flash_attention(jnp.swapaxes(q, 1, 2), jnp.swapaxes(kr, 1, 2),
-                          jnp.swapaxes(vr, 1, 2),
-                          causal=cfg.causal, block_kv=cfg.block_kv)
+    out = prefill_attention(jnp.swapaxes(q, 1, 2), jnp.swapaxes(kr, 1, 2),
+                            jnp.swapaxes(vr, 1, 2), cfg.causal,
+                            block_kv=cfg.block_kv)
     out = jnp.swapaxes(out, 1, 2).reshape(b, s, cfg.n_heads * cfg.head_dim)
     return out @ params["wo"].astype(x.dtype), (kc, vc)
 
@@ -270,10 +286,11 @@ def _mla_qkv_full(params: Params, cfg: AttnConfig, x: jax.Array,
 
 
 def _mla_expanded(params: Params, cfg: AttnConfig, x: jax.Array,
-                  positions: jax.Array | None):
-    """Full-sequence MLA: expand the latent to per-head K/V, then flash.
-    Returns the attention's output and the latent and rope key it
-    computed on the way."""
+                  positions: jax.Array | None, attend):
+    """Full-sequence MLA: expand the latent to per-head K/V, then
+    ``attend(q, k, v, causal=, block_kv=)`` (``flash_attention`` or
+    ``prefill_attention``).  Returns the attention's output and the
+    latent and rope key it computed on the way."""
     b, s, _ = x.shape
     hd, r = cfg.head_dim, cfg.rope_head_dim
     if positions is None:
@@ -287,7 +304,7 @@ def _mla_expanded(params: Params, cfg: AttnConfig, x: jax.Array,
     k = jnp.concatenate([k_nope, jnp.broadcast_to(
         k_rope, (b, s, cfg.n_heads, r))], axis=-1)
     v_pad = jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, r)))
-    out = flash_attention(
+    out = attend(
         jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2),
         jnp.swapaxes(v_pad, 1, 2), causal=cfg.causal, block_kv=cfg.block_kv)
     out = jnp.swapaxes(out, 1, 2)[..., :hd].reshape(b, s, cfg.n_heads * hd)
@@ -297,15 +314,17 @@ def _mla_expanded(params: Params, cfg: AttnConfig, x: jax.Array,
 @jax.named_scope("attn")
 def mla_apply(params: Params, cfg: AttnConfig, x: jax.Array,
               positions: jax.Array | None = None) -> jax.Array:
-    """Full-sequence (train / prefill) MLA, in the expanded form."""
-    return _mla_expanded(params, cfg, x, positions)[0]
+    """Full-sequence MLA (training and scoring; differentiable), in the
+    expanded form."""
+    return _mla_expanded(params, cfg, x, positions, flash_attention)[0]
 
 
 @jax.named_scope("attn")
 def mla_prefill(params: Params, cfg: AttnConfig, x: jax.Array,
                 positions: jax.Array | None = None):
     """Cache only the latent (c_kv) + shared rope key — MLA's memory win."""
-    out, c_kv, k_rope = _mla_expanded(params, cfg, x, positions)
+    out, c_kv, k_rope = _mla_expanded(params, cfg, x, positions,
+                                      prefill_attention)
     return out, (c_kv, k_rope[:, :, 0, :])
 
 

@@ -137,12 +137,45 @@ def test_chip_smoke_phases_at_tiny_size():
         dict(v=1024, d=256, n_banks=4, n=33), require_mosaic=False)
 
 
+def test_chip_smoke_reference_prefill_keeps_the_scan(monkeypatch):
+    """The serve phase's float32 reference runs its attention through
+    the XLA scan, not through the prefill kernel it checks."""
+    sys.path.insert(0, str(ROOT))
+    import chip_smoke
+    import jax.numpy as jnp
+
+    from repro.configs import get_arch, tiny_variant
+    from repro.models import attention
+
+    def refuse(*a, **kw):
+        raise AssertionError("the reference reached the prefill kernel")
+
+    monkeypatch.setattr(attention, "flash_prefill", refuse)
+    arch = tiny_variant(get_arch(chip_smoke.ARCH))
+    tokens = jnp.arange(2 * 16, dtype=jnp.int32).reshape(2, 16) % arch.vocab
+    logits = chip_smoke._reference_prefill(arch, tokens, 20)
+    assert logits.shape == (2, arch.vocab)
+
+
 def test_chip_smoke_sharded_phase_on_four_virtual_devices():
     """``--chips 4``'s path at tiny size on 4 virtual CPU devices: the
-    2x2 mesh, the placement check and the one-device comparison."""
-    code = ("import chip_smoke; "
+    2x2 mesh, the placement check and the one-device comparison.  The
+    sharded prefill is traced under the mesh, so its attention takes the
+    scan (a reused one-device trace would hold the kernel, which XLA
+    cannot partition on the chip); the one-device prefill takes the
+    kernel."""
+    code = ("import chip_smoke\n"
+            "from repro.launch.sharding import partitioned\n"
+            "from repro.models import attention\n"
+            "seen = []\n"
+            "inner = attention.prefill_attention\n"
+            "def spy(*a, **kw):\n"
+            "    seen.append(partitioned())\n"
+            "    return inner(*a, **kw)\n"
+            "attention.prefill_attention = spy\n"
             "chip_smoke.phase_sharded('tiny', batch=8, prompt_len=16, "
-            "steps=3)")
+            "steps=3)\n"
+            "print('prefill_attention_partitioned', sorted(set(seen)))\n")
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=4",
                PYTHONPATH=os.pathsep.join([str(ROOT), str(ROOT / "src")]))
@@ -150,3 +183,4 @@ def test_chip_smoke_sharded_phase_on_four_virtual_devices():
                          capture_output=True, text=True, timeout=300)
     assert out.returncode == 0, out.stderr[-2000:]
     assert "[sharded.serve]" in out.stdout
+    assert "prefill_attention_partitioned [False, True]" in out.stdout

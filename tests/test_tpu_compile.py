@@ -9,6 +9,9 @@ whether a real-width step program compiles at all:
     mamba2-130m chunk geometry, qwen3's embedding table, qwen3's layer
     matrices), each lowered with Mosaic (``tpu_custom_call`` in the HLO);
   * the weight-streaming kernel at minicpm3-4b's widths;
+  * the prefill attention kernel at qwen3-1.7b's and minicpm3-4b's
+    prefill shapes, and qwen3-1.7b's prefill step at the benchmark's
+    batch, which holds no float32 score block;
   * the qwen3-1.7b full-width decode step on one chip, and minicpm3-4b's
     at its published widths and the benchmark's 31 layers, whose layer
     matrices are read by the weight-streaming kernel (all of qwen3's;
@@ -117,6 +120,44 @@ def test_weight_stream_compiles_with_mosaic(one_chip, k, widths):
                  *[_shape(one_chip, (28, k, n), jnp.float32) for n in widths])
     assert "tpu_custom_call" in c.as_text()
     assert c.memory_analysis().temp_size_in_bytes < 2**20
+
+
+@pytest.mark.parametrize("b,hq,hkv,s,d", [
+    (4, 16, 8, 2048, 128),          # qwen3-1.7b's prefill
+    (1, 40, 40, 16384, 96),         # minicpm3-4b's 16k document (MLA)
+])
+def test_flash_prefill_compiles_with_mosaic(one_chip, b, hq, hkv, s, d):
+    from repro.kernels import flash_prefill
+
+    kv = _shape(one_chip, (b, hkv, s, d), jnp.bfloat16)
+    c = _compile(lambda q, k, v: flash_prefill(q, k, v, mode="pallas"),
+                 _shape(one_chip, (b, hq, s, d), jnp.bfloat16), kv, kv)
+    assert "tpu_custom_call" in c.as_text()
+
+
+def test_qwen3_prefill_step_holds_no_score_block(one_chip, monkeypatch):
+    """qwen3-1.7b's prefill step at the prefill cell's batch of 4 x 2048:
+    attention runs in the kernel, so the program holds none of the XLA
+    scan's f32[4,8,2,2048,1024] score blocks, and fewer temporaries
+    than the scan's 4,330,360,832 bytes."""
+    from repro.configs import get_arch
+    from repro.configs.base import RuntimeConfig
+    from repro.launch.steps import make_prefill_step
+    from repro.models import DTypePolicy, init_model
+
+    arch, policy = get_arch("qwen3-1.7b"), DTypePolicy.standard()
+    params = jax.tree.map(
+        lambda s: _shape(one_chip, s.shape, s.dtype),
+        jax.eval_shape(lambda k: init_model(k, arch, policy),
+                       jax.random.PRNGKey(0)))
+    monkeypatch.setenv("REPRO_KERNEL_MODE", "pallas")
+    c = _compile(make_prefill_step(arch, RuntimeConfig(remat="none"),
+                                   policy, 2049),
+                 params, {"tokens": _shape(one_chip, (4, 2048), jnp.int32)})
+    hlo = c.as_text()
+    assert "flash_prefill" in hlo and "tpu_custom_call" in hlo
+    assert "f32[4,8,2,2048,1024]" not in hlo
+    assert c.memory_analysis().temp_size_in_bytes < 4_330_360_832
 
 
 def _decode_step_streams_every_stack(one_chip, monkeypatch, arch, seq_len,
