@@ -24,7 +24,8 @@ from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.steps import make_decode_step, make_prefill_step
 from repro.memory import plan_memory
 from repro.configs.base import SHAPES
-from repro.models import DTypePolicy, init_model, make_cache
+from repro.models import DTypePolicy, init_model
+from repro.models.lm import layer_parts
 
 
 def main(argv=None) -> dict:
@@ -56,42 +57,35 @@ def main(argv=None) -> dict:
     tokens = jnp.asarray(
         rng.integers(0, arch.vocab, (args.batch, args.prompt_len)), jnp.int32)
     batch = {"tokens": tokens}
-    if arch.family == "vlm":
+    parts = layer_parts(arch)
+    if parts.patches:
         batch["patches"] = jnp.asarray(
             rng.standard_normal((args.batch, arch.n_patches, arch.vit_dim)),
             jnp.float32)
+        cache_len += arch.n_patches
+    if parts.cross:
+        batch["frames"] = jnp.asarray(
+            rng.standard_normal((args.batch, args.prompt_len, arch.d_model)),
+            jnp.float32)
 
-    from_empty = arch.family in ("hybrid",) or arch.is_encdec
+    prefill_jit = jax.jit(make_prefill_step(arch, rt, policy, cache_len))
     decode = jax.jit(make_decode_step(arch, rt, policy))
     # compile both steps before any timer starts, so the times below are
     # of the steps alone
     t0 = time.perf_counter()
-    if from_empty:
-        cache_spec = jax.eval_shape(
-            lambda: make_cache(arch, cache_len, args.batch, policy))
-    else:
-        prefill_jit = jax.jit(make_prefill_step(arch, rt, policy, cache_len))
-        prefill_step = prefill_jit.lower(params, batch).compile()
-        cache_spec = jax.eval_shape(prefill_jit, params, batch)[1]
+    prefill_step = prefill_jit.lower(params, batch).compile()
+    cache_spec = jax.eval_shape(prefill_jit, params, batch)[1]
     decode_step = decode.lower(
         params, cache_spec,
         jax.ShapeDtypeStruct((args.batch, 1), jnp.int32)).compile()
     print(f"compile (prefill and decode steps): "
           f"{time.perf_counter() - t0:.3f}s")
 
-    if from_empty:
-        # drivers for these families decode from an empty cache
-        cache = make_cache(arch, cache_len, args.batch, policy)
-        if arch.is_encdec:
-            print("enc-dec: decoding against zero cross-cache (driver demo)")
-        last = tokens[:, :1]
-        logits = None
-    else:
-        t0 = time.perf_counter()
-        logits, cache = jax.block_until_ready(prefill_step(params, batch))
-        t_prefill = time.perf_counter() - t0
-        print(f"prefill {args.batch}x{args.prompt_len}: {t_prefill:.3f}s")
-        last = jnp.argmax(logits[:, -1:, :], axis=-1).astype(jnp.int32)
+    t0 = time.perf_counter()
+    logits, cache = jax.block_until_ready(prefill_step(params, batch))
+    t_prefill = time.perf_counter() - t0
+    print(f"prefill {args.batch}x{args.prompt_len}: {t_prefill:.3f}s")
+    last = jnp.argmax(logits[:, -1:, :], axis=-1).astype(jnp.int32)
     prefill_logits = logits
 
     outs = []

@@ -2,26 +2,25 @@
 latent attention, MiniCPM3/DeepSeek-V2 style).
 
 Full-sequence attention is computed *blockwise* over KV chunks with an
-online-softmax accumulator (flash-attention recurrence in pure JAX via
-``lax.scan``) so the [S, S] score matrix is never materialized — at
-prefill_32k a materialized score tensor would be O(S^2) HBM and the
-dry-run would not fit.  Training and the cross attention use this scan
-(it is differentiable).  A prefill's causal self-attention goes through
-``prefill_attention``: the Pallas kernel ``kernels/flash_prefill.py``,
+online-softmax accumulator (``flash_attention``: the flash-attention
+recurrence in pure JAX via ``lax.scan``) so the [S, S] score matrix is
+never materialized — at prefill_32k a materialized score tensor would
+be O(S^2) HBM and the dry-run would not fit.  ``gqa_apply`` and
+``mla_apply`` take the function that runs a causal self-attention:
+training passes the scan (it is differentiable); a prefill passes
+``prefill_attention``, the Pallas kernel ``kernels/flash_prefill.py``,
 which keeps each score block on-chip and skips the key blocks past the
 diagonal, unless a launcher partitions the program (XLA cannot
-partition a kernel), where it falls back to the scan.
+partition a kernel), where it falls back to the scan.  Both return the
+layer's cache entries beside the output.
 
-Decode (single new token against a cached KV of length S) is a separate
-path; with ``kv_seq_shard`` the cache's length axis is sharded over the
-"model" mesh axis and XLA inserts the partial-softmax reduction
-(baseline) — the shard_map flash-decode in lm.py is the optimized form.
+Decode (one new token against a cached KV of length S) scores the whole
+cache in XLA; a launcher that shards the cache's length axis over the
+"model" mesh axis gets XLA's partial-softmax reduction.
 """
 from __future__ import annotations
 
 import dataclasses
-from functools import partial
-from typing import Any
 
 import jax
 import jax.numpy as jnp
@@ -64,8 +63,8 @@ class AttnConfig:
 SCORE_BLOCK_BYTES = 2**29
 
 
-def _flash_block_scan(q, k, v, causal: bool, q_offset, block_kv: int,
-                      bias=None):
+def flash_attention(q, k, v, causal: bool = True, q_offset=0,
+                    block_kv: int = 1024):
     """Online-softmax attention.
 
     q: [B, Hq, Sq, D]; k/v: [B, Hkv, Skv, D]; returns [B, Hq, Sq, D].
@@ -122,10 +121,6 @@ def _flash_block_scan(q, k, v, causal: bool, q_offset, block_kv: int,
     return out.reshape(b, hq, sq, d).astype(q.dtype)
 
 
-def flash_attention(q, k, v, *, causal=True, q_offset=0, block_kv=1024):
-    return _flash_block_scan(q, k, v, causal, q_offset, block_kv)
-
-
 def prefill_attention(q, k, v, causal: bool, block_kv: int = 1024):
     """A prefill's self-attention of a prompt to itself (q: [B, Hq, S,
     D]; k/v: [B, Hkv, S, D]): causal attention takes the Pallas kernel,
@@ -133,7 +128,7 @@ def prefill_attention(q, k, v, causal: bool, block_kv: int = 1024):
     anything else takes the scan with key blocks of ``block_kv``."""
     if causal and not partitioned():
         return flash_prefill(q, k, v)
-    return _flash_block_scan(q, k, v, causal, 0, block_kv)
+    return flash_attention(q, k, v, causal, 0, block_kv)
 
 
 # ======================================================================
@@ -181,35 +176,38 @@ def _replicate_kv(cfg: AttnConfig, k: jax.Array, v: jax.Array):
 
 @jax.named_scope("attn")
 def gqa_apply(params: Params, cfg: AttnConfig, x: jax.Array,
-              positions: jax.Array | None = None) -> jax.Array:
-    """Full-sequence GQA (training and scoring; differentiable)."""
+              attend=flash_attention):
+    """Full-sequence GQA.  ``attend(q, k, v, causal=, block_kv=)`` runs
+    the attention itself: ``flash_attention`` for training and scoring,
+    ``prefill_attention`` in a prefill.  Returns the output and the
+    layer's cache entries, K and V of the real heads [B, Hkv, S, D]."""
     b, s, _ = x.shape
-    if positions is None:
-        positions = jnp.arange(s)
-    q, k, v = _project_qkv(params, cfg, x, positions)
-    k, v = _replicate_kv(cfg, k, v)
-    out = flash_attention(
-        jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2),
-        causal=cfg.causal, block_kv=cfg.block_kv)
-    out = jnp.swapaxes(out, 1, 2).reshape(b, s, cfg.n_heads * cfg.head_dim)
-    return out @ params["wo"].astype(x.dtype)
-
-
-@jax.named_scope("attn")
-def gqa_prefill(params: Params, cfg: AttnConfig, x: jax.Array,
-                positions: jax.Array | None = None):
-    """Returns (attn_out, (k_cache, v_cache)) with caches [B, Hkv, S, D]."""
-    b, s, _ = x.shape
-    if positions is None:
-        positions = jnp.arange(s)
-    q, k, v = _project_qkv(params, cfg, x, positions)
+    q, k, v = _project_qkv(params, cfg, x, jnp.arange(s))
     kc, vc = jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2)  # cache: real heads
     kr, vr = _replicate_kv(cfg, k, v)
-    out = prefill_attention(jnp.swapaxes(q, 1, 2), jnp.swapaxes(kr, 1, 2),
-                            jnp.swapaxes(vr, 1, 2), cfg.causal,
-                            block_kv=cfg.block_kv)
+    out = attend(jnp.swapaxes(q, 1, 2), jnp.swapaxes(kr, 1, 2),
+                 jnp.swapaxes(vr, 1, 2), causal=cfg.causal,
+                 block_kv=cfg.block_kv)
     out = jnp.swapaxes(out, 1, 2).reshape(b, s, cfg.n_heads * cfg.head_dim)
     return out @ params["wo"].astype(x.dtype), (kc, vc)
+
+
+def _attend_cache(q: jax.Array, kc: jax.Array, vc: jax.Array,
+                  length: jax.Array | None = None) -> jax.Array:
+    """One query a row against a cache: q [B, 1, Hq, D]; kc/vc [B, Hkv,
+    S, D]; positions past ``length`` masked (none without it).  Returns
+    the float32 context [B, 1, Hq * D]."""
+    b, _, hq, hd = q.shape
+    hkv = kc.shape[1]
+    qg = jnp.swapaxes(q, 1, 2).reshape(b, hkv, hq // hkv, hd)
+    scores = jnp.einsum("bhgd,bhsd->bhgs", qg.astype(jnp.float32),
+                        kc.astype(jnp.float32)) / jnp.sqrt(hd)
+    if length is not None:
+        valid = jnp.arange(kc.shape[2])[None, None, None, :] <= length
+        scores = jnp.where(valid, scores, -jnp.inf)
+    w = jax.nn.softmax(scores, axis=-1)
+    out = jnp.einsum("bhgs,bhsd->bhgd", w, vc.astype(jnp.float32))
+    return out.reshape(b, 1, hq * hd)
 
 
 @jax.named_scope("attn")
@@ -218,8 +216,6 @@ def gqa_decode(params: Params, cfg: AttnConfig, x: jax.Array,
                matmul=cast_matmul):
     """One-token decode. x: [B, 1, D_model]; cache [B, Hkv, S_max, D].
     ``matmul`` multiplies by the projections (``common.cast_matmul``)."""
-    b = x.shape[0]
-    hd = cfg.head_dim
     positions = jnp.full((1,), cache_len, jnp.int32)
     q, k, v = _project_qkv(params, cfg, x, positions, matmul)
     kc, vc = cache
@@ -227,19 +223,40 @@ def gqa_decode(params: Params, cfg: AttnConfig, x: jax.Array,
         kc, jnp.swapaxes(k, 1, 2).astype(kc.dtype), cache_len, axis=2)
     vc = jax.lax.dynamic_update_slice_in_dim(
         vc, jnp.swapaxes(v, 1, 2).astype(vc.dtype), cache_len, axis=2)
-    s_max = kc.shape[2]
-    qh = jnp.swapaxes(q, 1, 2)                       # [B, Hq, 1, D]
-    g = cfg.n_heads // cfg.n_kv_heads
-    qg = qh.reshape(b, cfg.n_kv_heads, g, hd)
-    scores = jnp.einsum("bhgd,bhsd->bhgs", qg.astype(jnp.float32),
-                        kc.astype(jnp.float32)) / jnp.sqrt(hd)
-    valid = jnp.arange(s_max)[None, None, None, :] <= cache_len
-    scores = jnp.where(valid, scores, -jnp.inf)
-    w = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bhgs,bhsd->bhgd", w, vc.astype(jnp.float32))
-    out = out.reshape(b, 1, cfg.n_heads * hd).astype(x.dtype)
+    out = _attend_cache(q, kc, vc, cache_len).astype(x.dtype)
     (out,) = matmul(out, params["wo"])
     return out, (kc, vc)
+
+
+@jax.named_scope("attn")
+def cross_apply(params: Params, cfg: AttnConfig, x: jax.Array,
+                enc: jax.Array) -> tuple[jax.Array, tuple]:
+    """Full-sequence cross attention: queries from ``x`` [B, S, D], keys
+    and values from the encoder's output ``enc`` [B, S_enc, D], no rope
+    and no mask.  Returns the output and the layer's cache entries, the
+    cross K and V [B, Hkv, S_enc, D]."""
+    b, s, _ = x.shape
+    hd = cfg.head_dim
+    q = (x @ params["wq"].astype(x.dtype)).reshape(b, s, cfg.n_heads, hd)
+    enc = enc.astype(x.dtype)
+    k = (enc @ params["wk"].astype(x.dtype)).reshape(b, -1, cfg.n_kv_heads, hd)
+    v = (enc @ params["wv"].astype(x.dtype)).reshape(b, -1, cfg.n_kv_heads, hd)
+    k, v = jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2)
+    o = flash_attention(jnp.swapaxes(q, 1, 2), k, v, causal=False)
+    o = jnp.swapaxes(o, 1, 2).reshape(b, s, cfg.n_heads * hd)
+    return o @ params["wo"].astype(x.dtype), (k, v)
+
+
+@jax.named_scope("attn")
+def cross_decode(params: Params, cfg: AttnConfig, x: jax.Array,
+                 cache: tuple[jax.Array, jax.Array]) -> tuple[jax.Array, tuple]:
+    """One-token cross attention against the cross cache (K and V
+    [B, Hkv, S_enc, D], which decode leaves as they are)."""
+    b = x.shape[0]
+    q = (x @ params["wq"].astype(x.dtype)).reshape(b, 1, cfg.n_heads,
+                                                   cfg.head_dim)
+    out = _attend_cache(q, *cache).astype(x.dtype)
+    return out @ params["wo"].astype(x.dtype), cache
 
 
 # ======================================================================
@@ -285,17 +302,18 @@ def _mla_qkv_full(params: Params, cfg: AttnConfig, x: jax.Array,
     return q_nope, q_rope, c_kv, k_rope
 
 
-def _mla_expanded(params: Params, cfg: AttnConfig, x: jax.Array,
-                  positions: jax.Array | None, attend):
-    """Full-sequence MLA: expand the latent to per-head K/V, then
-    ``attend(q, k, v, causal=, block_kv=)`` (``flash_attention`` or
-    ``prefill_attention``).  Returns the attention's output and the
-    latent and rope key it computed on the way."""
+@jax.named_scope("attn")
+def mla_apply(params: Params, cfg: AttnConfig, x: jax.Array,
+              attend=flash_attention):
+    """Full-sequence MLA in the expanded form: the latent expanded to
+    per-head K/V, then ``attend(q, k, v, causal=, block_kv=)``
+    (``flash_attention`` or ``prefill_attention``).  Returns the output
+    and the layer's cache entries: only the latent ``c_kv`` [B, S, kvr]
+    and the shared rope key [B, S, r], MLA's memory win."""
     b, s, _ = x.shape
     hd, r = cfg.head_dim, cfg.rope_head_dim
-    if positions is None:
-        positions = jnp.arange(s)
-    q_nope, q_rope, c_kv, k_rope = _mla_qkv_full(params, cfg, x, positions)
+    q_nope, q_rope, c_kv, k_rope = _mla_qkv_full(params, cfg, x,
+                                                 jnp.arange(s))
     k_nope = (c_kv @ params["wk_b"].astype(x.dtype)).reshape(
         b, s, cfg.n_heads, hd)
     v = (c_kv @ params["wv_b"].astype(x.dtype)).reshape(b, s, cfg.n_heads, hd)
@@ -308,24 +326,7 @@ def _mla_expanded(params: Params, cfg: AttnConfig, x: jax.Array,
         jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2),
         jnp.swapaxes(v_pad, 1, 2), causal=cfg.causal, block_kv=cfg.block_kv)
     out = jnp.swapaxes(out, 1, 2)[..., :hd].reshape(b, s, cfg.n_heads * hd)
-    return out @ params["wo"].astype(x.dtype), c_kv, k_rope
-
-
-@jax.named_scope("attn")
-def mla_apply(params: Params, cfg: AttnConfig, x: jax.Array,
-              positions: jax.Array | None = None) -> jax.Array:
-    """Full-sequence MLA (training and scoring; differentiable), in the
-    expanded form."""
-    return _mla_expanded(params, cfg, x, positions, flash_attention)[0]
-
-
-@jax.named_scope("attn")
-def mla_prefill(params: Params, cfg: AttnConfig, x: jax.Array,
-                positions: jax.Array | None = None):
-    """Cache only the latent (c_kv) + shared rope key — MLA's memory win."""
-    out, c_kv, k_rope = _mla_expanded(params, cfg, x, positions,
-                                      prefill_attention)
-    return out, (c_kv, k_rope[:, :, 0, :])
+    return out @ params["wo"].astype(x.dtype), (c_kv, k_rope[:, :, 0, :])
 
 
 @jax.named_scope("attn")

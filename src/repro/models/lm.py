@@ -1,16 +1,23 @@
 """Unified language-model assembly for all assigned architectures.
 
 One functional model covering the six families (dense / moe / ssm /
-hybrid / vlm / audio-encdec).  Layers are pre-stacked and consumed with
-``lax.scan`` (+ per-layer remat), so HLO size and compile time are O(1)
-in depth — required for 96-layer, 340B-parameter dry-runs.
+hybrid / vlm / audio-encdec).  ``layer_parts`` reads the family from the
+``ArchConfig`` once and names a layer's parts: its mixer (GQA, MLA or
+Mamba2), cross attention or none, MLP, experts or none, and the shared
+attention block or none; everything below follows it.  Layers are
+pre-stacked and consumed with ``lax.scan`` (+ per-layer remat), so HLO
+size and compile time are O(1) in depth — required for 96-layer,
+340B-parameter dry-runs.  One full-sequence layer (``_layer``) serves
+training, scoring and prefill, which differ only in the function that
+runs a causal self-attention; the decode step scans one body that runs
+the same layer with each mixer's one-token form.
 
 Public entry points:
   init_model(key, arch, policy)                  -> params
-  forward(params, arch, batch, rt)               -> logits (train/prefill)
+  forward(params, arch, batch, rt)               -> (logits, aux_loss)
   loss_fn(params, arch, batch, rt)               -> (loss, metrics)
-  make_cache(arch, shape, batch, policy)         -> decode cache pytree
-  prefill(params, arch, batch, rt)               -> (logits, cache)
+  make_cache(arch, seq_len, batch, policy)       -> decode cache pytree
+  prefill(params, arch, batch, cache_len, rt)    -> (last logits, cache)
   decode_step(params, arch, cache, tokens, rt)   -> (logits, cache)
 
 Activation-sharding hooks go through ``repro.launch.sharding.constrain``
@@ -19,19 +26,18 @@ so the model code stays mesh-agnostic.
 from __future__ import annotations
 
 import dataclasses
-from functools import partial
-from typing import Any
+from typing import Callable
 
 import jax
 import jax.numpy as jnp
 
-from repro.configs.base import ArchConfig, RuntimeConfig, ShapeConfig
+from repro.configs.base import ArchConfig, RuntimeConfig
 from repro.kernels import weight_stream
 from repro.launch.sharding import constrain, partitioned
-from repro.models.attention import (AttnConfig, flash_attention, gqa_apply,
-                                    gqa_decode, gqa_init, gqa_prefill,
-                                    mla_apply, mla_decode, mla_init,
-                                    mla_prefill)
+from repro.models import attention
+from repro.models.attention import (AttnConfig, cross_apply, cross_decode,
+                                    gqa_apply, gqa_decode, gqa_init,
+                                    mla_apply, mla_decode, mla_init)
 from repro.models.common import (DTypePolicy, Params, cast_matmul, dense_init,
                                  norm_init, rms_norm, truncated_normal_init)
 from repro.models.mlp import mlp_apply, mlp_init
@@ -82,51 +88,59 @@ def ssm_config(arch: ArchConfig) -> SSMConfig:
 
 
 # ======================================================================
-# Per-layer blocks
+# The layer's parts: the one place that reads the family
 # ======================================================================
-def _attn_block_init(key, arch: ArchConfig) -> Params:
-    acfg = attn_config(arch)
-    init = mla_init if arch.attn_type == "mla" else gqa_init
-    return {"attn": init(key, acfg), "ln": norm_init(arch.d_model)}
+@dataclasses.dataclass(frozen=True)
+class Parts:
+    mixer: str                 # "gqa" | "mla" | "mamba"
+    ffn: str | None            # "mlp" | "moe" | None
+    cross: bool = False        # cross attention to an encoder's output
+    shared_every: int = 0      # zamba2's shared block after every n-th layer
+    patches: bool = False      # a projected image prefix before the tokens
+    causal: bool = True        # the mixer's attention (False: an encoder's)
 
 
-def _decoder_layer_init(key, arch: ArchConfig) -> Params:
+def layer_parts(arch: ArchConfig) -> Parts:
+    """The parts of ``arch``'s decoder layer, and the model's extras: an
+    encoder (which cross attention reads) and an image prefix."""
+    ssm = arch.family in ("ssm", "hybrid")
+    return Parts(
+        mixer="mamba" if ssm else arch.attn_type,
+        ffn=None if ssm else ("moe" if arch.family == "moe" else "mlp"),
+        cross=arch.is_encdec,
+        shared_every=arch.shared_attn_every if arch.family == "hybrid" else 0,
+        patches=arch.family == "vlm")
+
+
+# the shared block's layer, and the encoder's
+_GQA_MLP = Parts("gqa", "mlp")
+_ENCODER = Parts("gqa", "mlp", causal=False)
+# each mixer's decode-cache entries, by their cache keys
+_CACHE_KEYS = {"gqa": ("k", "v"), "mla": ("c_kv", "k_rope"),
+               "mamba": ("ssm_h", "ssm_conv")}
+# the entries that hold a row per position, [..., S, D]: prefill pads
+# them to the cache's length (the cross K/V keep the encoder's length)
+_PER_POSITION = ("k", "v", "c_kv", "k_rope", "shared_k", "shared_v")
+
+
+def _layer_init(key, arch: ArchConfig, parts: Parts) -> Params:
     k1, k2, k3 = jax.random.split(key, 3)
-    p = _attn_block_init(k1, arch)
-    p["ln2"] = norm_init(arch.d_model)
-    if arch.family == "moe":
-        p["moe"] = moe_init(k2, moe_config(arch))
+    d = arch.d_model
+    p: Params = {"ln": norm_init(d)}
+    if parts.mixer == "mamba":
+        p["mamba"] = mamba2_init(k1, ssm_config(arch))
     else:
-        p["mlp"] = mlp_init(k2, arch.d_model, arch.d_ff, arch.gated_mlp)
-    return p
-
-
-def _ssm_layer_init(key, arch: ArchConfig) -> Params:
-    return {"mamba": mamba2_init(key, ssm_config(arch)),
-            "ln": norm_init(arch.d_model)}
-
-
-def _encoder_layer_init(key, arch: ArchConfig) -> Params:
-    k1, k2 = jax.random.split(key)
-    acfg = attn_config(arch, causal=False)
-    return {"attn": gqa_init(k1, acfg), "ln": norm_init(arch.d_model),
-            "mlp": mlp_init(k2, arch.d_model, arch.d_ff, arch.gated_mlp),
-            "ln2": norm_init(arch.d_model)}
-
-
-def _cross_decoder_layer_init(key, arch: ArchConfig) -> Params:
-    k1, k2, k3 = jax.random.split(key, 3)
-    p = _decoder_layer_init(jax.random.fold_in(k1, 0), arch)
-    p["cross"] = gqa_init(k2, attn_config(arch, causal=False))
-    p["ln_cross"] = norm_init(arch.d_model)
-    return p
-
-
-def _shared_block_init(key, arch: ArchConfig) -> Params:
-    """zamba2-style shared attention block, fed concat(h, emb0)."""
-    k1, k2, k3 = jax.random.split(key, 3)
-    p = _decoder_layer_init(k1, arch)
-    p["w_cat"] = dense_init(k2, 2 * arch.d_model, arch.d_model)
+        init = mla_init if parts.mixer == "mla" else gqa_init
+        p["attn"] = init(k1, attn_config(arch))
+    if parts.cross:
+        p["cross"] = gqa_init(k3, attn_config(arch, causal=False))
+        p["ln_cross"] = norm_init(d)
+    if parts.ffn:
+        p["ln2"] = norm_init(d)
+    if parts.ffn == "moe":
+        p["moe"] = moe_init(k2, moe_config(arch))
+    elif parts.ffn == "mlp":
+        p["mlp"] = mlp_init(k2, d, arch.d_ff, arch.gated_mlp)
     return p
 
 
@@ -138,52 +152,59 @@ def _residual(arch: ArchConfig, h: jax.Array, branch: jax.Array) -> jax.Array:
     return h + branch
 
 
-def _layer_apply_full(p: Params, arch: ArchConfig, h: jax.Array,
-                      rt: RuntimeConfig) -> tuple[jax.Array, jax.Array]:
-    """Full-sequence decoder layer (train / prefill w/o cache).
-    Returns (h, aux_loss)."""
-    aux = jnp.zeros((), jnp.float32)
+Mix = Callable[[Params, jax.Array], tuple[jax.Array, tuple]]
+
+
+def _layer(p: Params, arch: ArchConfig, parts: Parts, h: jax.Array,
+           rt: RuntimeConfig, mix: Mix, cross: Mix | None = None,
+           matmul=cast_matmul) -> tuple[jax.Array, jax.Array | None, dict]:
+    """One decoder layer of ``parts``: the mixer, then cross attention,
+    then the MLP or the experts, each normed first and added to the
+    residual.  ``mix(params, x)`` and ``cross(params, x)`` return their
+    output and their cache entries; the full-sequence layer (forward,
+    prefill) and the decode step's differ only in them and in
+    ``matmul``, the MLP's (``common.cast_matmul``).  Returns (h, the
+    experts' load-balance loss or None, the cache entries by key)."""
     # Megatron-SP: sub-block outputs are constrained to the seq-sharded
     # "hidden" layout BEFORE the residual add, so the TP partial-sum
     # lowers to a reduce-scatter and the residual add stays local
     # (otherwise GSPMD all-gathers the residual at every add —
     # measured ~7 hidden-sized gathers/layer on mistral, §Perf it.4).
-    if arch.family in ("ssm", "hybrid"):
-        with jax.named_scope("mlp"):
-            x = constrain(rms_norm(h, p["ln"]["scale"]), "tp_in", rt)
-            h = _residual(arch, h, constrain(
-                mamba2_apply(p["mamba"], ssm_config(arch), x), "hidden", rt))
-        return constrain(h, "hidden", rt), aux
-    acfg = attn_config(arch)
-    with jax.named_scope("attn"):
+    ssm = parts.mixer == "mamba"
+    # the Mamba2 mixer is counted with the MLPs
+    with jax.named_scope("mlp" if ssm else "attn"):
         x = constrain(rms_norm(h, p["ln"]["scale"]), "tp_in", rt)
-        attn = mla_apply if arch.attn_type == "mla" else gqa_apply
-        h = _residual(arch, h, constrain(attn(p["attn"], acfg, x), "hidden",
-                                         rt))
-    with jax.named_scope("mlp"):
-        x2 = constrain(rms_norm(h, p["ln2"]["scale"]), "tp_in", rt)
-        if arch.family == "moe":
-            h = _residual(arch, h, constrain(
-                moe_apply(p["moe"], moe_config(arch), x2), "hidden", rt))
-            aux = aux_load_balance_loss(p["moe"], moe_config(arch), x2)
-        else:
-            h = _residual(arch, h, constrain(mlp_apply(p["mlp"], x2, arch.act),
-                                             "hidden", rt))
-    return constrain(h, "hidden", rt), aux
+        o, entries = mix(p["mamba" if ssm else "attn"], x)
+        h = _residual(arch, h, constrain(o, "hidden", rt))
+        entries = dict(zip(_CACHE_KEYS[parts.mixer], entries))
+        if parts.cross:
+            x = rms_norm(h, p["ln_cross"]["scale"])
+            o, (entries["cross_k"], entries["cross_v"]) = cross(p["cross"], x)
+            h = _residual(arch, h, o)
+    aux = None
+    if parts.ffn:
+        with jax.named_scope("mlp"):
+            x = constrain(rms_norm(h, p["ln2"]["scale"]), "tp_in", rt)
+            if parts.ffn == "moe":
+                o = moe_apply(p["moe"], moe_config(arch), x)
+                aux = aux_load_balance_loss(p["moe"], moe_config(arch), x)
+            else:
+                o = mlp_apply(p["mlp"], x, arch.act, matmul)
+            h = _residual(arch, h, constrain(o, "hidden", rt))
+    return constrain(h, "hidden", rt), aux, entries
 
 
-def _shared_block_apply(p: Params, arch: ArchConfig, h: jax.Array,
-                        emb0: jax.Array, rt: RuntimeConfig) -> jax.Array:
-    acfg = attn_config(arch)
+def _shared_block(p: Params, arch: ArchConfig, h: jax.Array,
+                  emb0: jax.Array, rt: RuntimeConfig, mix: Mix
+                  ) -> tuple[jax.Array, dict]:
+    """zamba2-style shared block: a GQA and MLP layer over concat(h,
+    emb0) projected by ``w_cat``, added to ``h``.  Returns h and the
+    block's K/V entries."""
     with jax.named_scope("attn"):
         z = jnp.concatenate([h, emb0.astype(h.dtype)], axis=-1)
         z = z @ p["w_cat"].astype(h.dtype)
-        x = rms_norm(z, p["ln"]["scale"])
-        z = z + gqa_apply(p["attn"], acfg, x)
-    with jax.named_scope("mlp"):
-        x2 = rms_norm(z, p["ln2"]["scale"])
-        z = z + mlp_apply(p["mlp"], x2, arch.act)
-    return h + z
+    z, _, kv = _layer(p, arch, _GQA_MLP, z, rt, mix)
+    return h + z, kv
 
 
 # ======================================================================
@@ -192,6 +213,7 @@ def _shared_block_apply(p: Params, arch: ArchConfig, h: jax.Array,
 def init_model(key: jax.Array, arch: ArchConfig,
                policy: DTypePolicy | None = None) -> Params:
     policy = policy or DTypePolicy.standard()
+    parts = layer_parts(arch)
     ks = jax.random.split(key, 8)
     d = arch.d_model
     params: Params = {
@@ -201,24 +223,18 @@ def init_model(key: jax.Array, arch: ArchConfig,
     }
     if not arch.tie_embeddings:
         params["head"] = dense_init(ks[1], d, arch.padded_vocab)
-
-    if arch.family in ("ssm", "hybrid"):
-        layer_init = partial(_ssm_layer_init, arch=arch)
-    elif arch.is_encdec:
-        layer_init = partial(_cross_decoder_layer_init, arch=arch)
-    else:
-        layer_init = partial(_decoder_layer_init, arch=arch)
-    params["blocks"] = jax.vmap(lambda k: layer_init(k))(
+    params["blocks"] = jax.vmap(lambda k: _layer_init(k, arch, parts))(
         jax.random.split(ks[2], arch.n_layers))
-
-    if arch.family == "hybrid" and arch.shared_attn_every:
-        params["shared"] = _shared_block_init(ks[3], arch)
-    if arch.is_encdec:
+    if parts.shared_every:
+        k1, k2, _ = jax.random.split(ks[3], 3)
+        params["shared"] = {**_layer_init(k1, arch, _GQA_MLP),
+                            "w_cat": dense_init(k2, 2 * d, d)}
+    if parts.cross:
         params["enc_blocks"] = jax.vmap(
-            lambda k: _encoder_layer_init(k, arch))(
+            lambda k: _layer_init(k, arch, _ENCODER))(
             jax.random.split(ks[4], arch.enc_layers))
         params["enc_norm"] = norm_init(d)
-    if arch.family == "vlm":
+    if parts.patches:
         params["patch_proj"] = dense_init(ks[5], arch.vit_dim, d)
 
     return jax.tree.map(
@@ -234,7 +250,8 @@ def _cast_blocks(blocks: Params, dtype) -> Params:
     """Cast the stacked layer matrices to the compute dtype ONCE, outside
     the layer scan, so FSDP all-gathers move bf16 (not f32) bytes.  A
     layer's matrices are rank >= 3 once stacked; its vectors (norm
-    scales etc.) are rank 2 and stay f32 (rms_norm computes in f32).
+    scales etc.) are rank 2 and stay f32 (rms_norm computes in f32), as
+    do the Mamba2 mixer's stacks (its convolution runs in f32).
     The layers cast each matrix to the compute dtype where they use it,
     so casting the stack and then slicing gives the same bits as slicing
     and then casting.  Training and prefill cast every stack here; the
@@ -248,102 +265,90 @@ def _cast_blocks(blocks: Params, dtype) -> Params:
     input is this scope's, not the bare parameter, so the trace counts
     the copy under ``weight_cast``."""
     blocks = jax.lax.optimization_barrier(blocks)
-    return jax.tree.map(
-        lambda x: x.astype(dtype) if (x.ndim >= 3 and
-                                      x.dtype == jnp.float32) else x,
+    return jax.tree_util.tree_map_with_path(
+        lambda path, x: x.astype(dtype) if (
+            x.ndim >= 3 and x.dtype == jnp.float32
+            and path[0].key != "mamba") else x,
         blocks)
 
 
-def _scan_layers(params: Params, arch: ArchConfig, h: jax.Array,
-                 rt: RuntimeConfig) -> tuple[jax.Array, jax.Array]:
+def _full_mix(arch: ArchConfig, parts: Parts, attend) -> Mix:
+    """The full-sequence mixer of ``parts``; ``attend`` runs a causal
+    self-attention (see ``attention.gqa_apply``)."""
+    if parts.mixer == "mamba":
+        scfg = ssm_config(arch)
+        return lambda p, x: mamba2_apply(p, scfg, x, return_state=True)
+    apply = mla_apply if parts.mixer == "mla" else gqa_apply
+    acfg = attn_config(arch, parts.causal)
+    return lambda p, x: apply(p, acfg, x, attend)
+
+
+def _layers(params: Params, blocks: Params, arch: ArchConfig, parts: Parts,
+            h: jax.Array, rt: RuntimeConfig, attend, cross: Mix | None = None
+            ) -> tuple[jax.Array, jax.Array, dict]:
+    """The full-sequence layer scan of forward and prefill over
+    ``blocks``.  Returns h, the summed aux loss and every cache entry
+    stacked over the layers (the shared block's over its uses)."""
     emb0 = h
-    every = arch.shared_attn_every
+    mix = _full_mix(arch, parts, attend)
+    every = parts.shared_every
 
-    def one_layer(carry, xs):
-        hh = carry
+    def one_layer(hh, xs):
         bp, idx = xs
-        hh, aux = _layer_apply_full(bp, arch, hh, rt)
-        if arch.family == "hybrid" and every:
-            hh = jax.lax.cond(
-                (idx % every) == 0,
-                lambda v: _shared_block_apply(params["shared"], arch, v,
-                                              emb0, rt),
-                lambda v: v,
-                hh,
-            )
-        return hh, aux
+        hh, aux, entries = _layer(bp, arch, parts, hh, rt, mix, cross)
+        if every:
+            shared = lambda v: _shared_block(    # noqa: E731
+                params["shared"], arch, v, emb0, rt,
+                _full_mix(arch, _GQA_MLP, attend))
+            # where the cond skips the block its entries are zeros,
+            # dropped after the scan
+            skip = lambda v: (v, jax.tree.map(   # noqa: E731
+                lambda s: jnp.zeros(s.shape, s.dtype),
+                jax.eval_shape(shared, v)[1]))
+            hh, kv = jax.lax.cond(idx % every == 0, shared, skip, hh)
+            entries.update(shared_k=kv["k"], shared_v=kv["v"])
+        return hh, (aux, entries)
 
     layer = one_layer
     if rt.remat == "full":
         layer = jax.checkpoint(
             one_layer, policy=jax.checkpoint_policies.nothing_saveable)
-    blocks = _cast_blocks(params["blocks"], h.dtype)
+    blocks = _cast_blocks(blocks, h.dtype)
+    # the layer index is read by the shared block's cond alone
+    idx = jnp.arange(arch.n_layers) if every else None
     with jax.named_scope("layers"):
-        h, auxs = jax.lax.scan(
-            layer, h, (blocks, jnp.arange(arch.n_layers)))
-    return h, jnp.sum(auxs)
+        h, (auxs, entries) = jax.lax.scan(layer, h, (blocks, idx))
+    if every:
+        # the layers that ran the shared block
+        entries = {k: c[::every] if k.startswith("shared_") else c
+                   for k, c in entries.items()}
+    aux = jnp.zeros((), jnp.float32) if auxs is None else jnp.sum(auxs)
+    return h, aux, entries
 
 
-def _encoder_forward(params: Params, arch: ArchConfig, frames: jax.Array,
-                     rt: RuntimeConfig) -> jax.Array:
-    acfg = attn_config(arch, causal=False)
-
-    def one_layer(h, bp):
-        with jax.named_scope("attn"):
-            x = rms_norm(h, bp["ln"]["scale"])
-            h = h + gqa_apply(bp["attn"], acfg, x)
-        with jax.named_scope("mlp"):
-            x2 = rms_norm(h, bp["ln2"]["scale"])
-            h = h + mlp_apply(bp["mlp"], x2, arch.act)
-        return constrain(h, "hidden", rt), None
-
-    layer = one_layer
-    if rt.remat == "full":
-        layer = jax.checkpoint(
-            one_layer, policy=jax.checkpoint_policies.nothing_saveable)
-    with jax.named_scope("layers"):
-        h, _ = jax.lax.scan(layer, frames, params["enc_blocks"])
-    return rms_norm(h, params["enc_norm"]["scale"])
-
-
-def _cross_decoder_forward(params: Params, arch: ArchConfig, h: jax.Array,
-                           enc_out: jax.Array, rt: RuntimeConfig
-                           ) -> tuple[jax.Array, jax.Array]:
-    acfg = attn_config(arch)
-    xcfg = attn_config(arch, causal=False)
-
-    def one_layer(hh, bp):
-        with jax.named_scope("attn"):
-            x = rms_norm(hh, bp["ln"]["scale"])
-            hh = _residual(arch, hh, gqa_apply(bp["attn"], acfg, x))
-            xc = rms_norm(hh, bp["ln_cross"]["scale"])
-            # cross attention: q from decoder, k/v from encoder output
-            b, s, _ = xc.shape
-            hd = xcfg.head_dim
-            q = (xc @ bp["cross"]["wq"].astype(xc.dtype)).reshape(
-                b, s, xcfg.n_heads, hd)
-            k = (enc_out.astype(xc.dtype)
-                 @ bp["cross"]["wk"].astype(xc.dtype)
-                 ).reshape(b, -1, xcfg.n_kv_heads, hd)
-            v = (enc_out.astype(xc.dtype)
-                 @ bp["cross"]["wv"].astype(xc.dtype)
-                 ).reshape(b, -1, xcfg.n_kv_heads, hd)
-            o = flash_attention(jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2),
-                                jnp.swapaxes(v, 1, 2), causal=False)
-            o = jnp.swapaxes(o, 1, 2).reshape(b, s, xcfg.n_heads * hd)
-            hh = _residual(arch, hh, o @ bp["cross"]["wo"].astype(xc.dtype))
-        with jax.named_scope("mlp"):
-            x2 = rms_norm(hh, bp["ln2"]["scale"])
-            hh = _residual(arch, hh, mlp_apply(bp["mlp"], x2, arch.act))
-        return constrain(hh, "hidden", rt), jnp.zeros((), jnp.float32)
-
-    layer = one_layer
-    if rt.remat == "full":
-        layer = jax.checkpoint(
-            one_layer, policy=jax.checkpoint_policies.nothing_saveable)
-    with jax.named_scope("layers"):
-        h, auxs = jax.lax.scan(layer, h, params["blocks"])
-    return h, jnp.sum(auxs)
+def _decoder(params: Params, arch: ArchConfig, batch: dict[str, jax.Array],
+             rt: RuntimeConfig, cd, attend
+             ) -> tuple[jax.Array, jax.Array, dict]:
+    """Forward's and prefill's common part: embed the tokens (after the
+    image prefix where the model has one), run the encoder where it has
+    one, then the decoder layers.  Returns ``_layers``'s answer."""
+    parts = layer_parts(arch)
+    h = embed_tokens(params, arch, batch["tokens"], rt, cd)
+    if parts.patches:
+        with jax.named_scope("embed"):
+            prefix = (batch["patches"].astype(cd)
+                      @ params["patch_proj"].astype(cd))
+            h = jnp.concatenate([prefix, h], axis=1)
+    cross = None
+    if parts.cross:
+        enc = _layers(params, params["enc_blocks"], arch, _ENCODER,
+                      batch["frames"].astype(cd), rt,
+                      attention.flash_attention)[0]
+        enc = rms_norm(enc, params["enc_norm"]["scale"])
+        xcfg = attn_config(arch, causal=False)
+        cross = lambda p, x: cross_apply(p, xcfg, x, enc)   # noqa: E731
+    return _layers(params, params["blocks"], arch, parts, h, rt, attend,
+                   cross)
 
 
 @jax.named_scope("embed")
@@ -380,24 +385,9 @@ def forward(params: Params, arch: ArchConfig, batch: dict[str, jax.Array],
     audio: + "frames" [B,S_enc,d_model]."""
     rt = rt or RuntimeConfig()
     policy = policy or DTypePolicy.standard()
-    cd = policy.compute
-    tokens = batch["tokens"]
-    h = embed_tokens(params, arch, tokens, rt, cd)
-
-    if arch.family == "vlm":
-        with jax.named_scope("embed"):
-            prefix = (batch["patches"].astype(cd)
-                      @ params["patch_proj"].astype(cd))
-            h = jnp.concatenate([prefix, h], axis=1)
-
-    if arch.is_encdec:
-        enc_out = _encoder_forward(params, arch,
-                                   batch["frames"].astype(cd), rt)
-        h, aux = _cross_decoder_forward(params, arch, h, enc_out, rt)
-    else:
-        h, aux = _scan_layers(params, arch, h, rt)
-
-    logits = _head(params, arch, h, cd)
+    h, aux, _ = _decoder(params, arch, batch, rt, policy.compute,
+                         attention.flash_attention)
+    logits = _head(params, arch, h, policy.compute)
     return constrain(logits, "logits", rt), aux
 
 
@@ -407,8 +397,8 @@ def loss_fn(params: Params, arch: ArchConfig, batch: dict[str, jax.Array],
     """Next-token cross entropy (+ MoE aux + z-loss)."""
     logits, aux = forward(params, arch, batch, rt, policy)
     labels = batch["labels"]
-    if arch.family == "vlm":  # logits cover [patches + tokens]
-        logits = logits[:, -labels.shape[1]:, :]
+    # logits cover an image prefix, where there is one, then the tokens
+    logits = logits[:, -labels.shape[1]:, :]
     lg = logits.astype(jnp.float32)
     m = jax.lax.stop_gradient(jnp.max(lg, axis=-1, keepdims=True))
     shifted = lg - m
@@ -430,72 +420,63 @@ def loss_fn(params: Params, arch: ArchConfig, batch: dict[str, jax.Array],
                   "tokens": jnp.sum(mask)}
 
 
+def prefill(params: Params, arch: ArchConfig, batch: dict[str, jax.Array],
+            cache_len: int, rt: RuntimeConfig | None = None,
+            policy: DTypePolicy | None = None) -> tuple[jax.Array, Params]:
+    """The full-sequence forward, whose causal self-attention runs
+    ``attention.prefill_attention``, and the decode cache it fills, of
+    capacity ``cache_len`` (>= the prompt with its image prefix).
+    Returns the last position's logits and the cache."""
+    rt = rt or RuntimeConfig()
+    policy = policy or DTypePolicy.standard()
+    cd = policy.compute
+    h, _, entries = _decoder(params, arch, batch, rt, cd,
+                             attention.prefill_attention)
+    s = h.shape[1]
+    cache = {}
+    with jax.named_scope("layers"):
+        for key, c in entries.items():
+            if key in _PER_POSITION:
+                pad = [(0, 0)] * c.ndim
+                pad[-2] = (0, cache_len - s)
+                c = jnp.pad(c, pad)
+            cache[key] = c
+    logits = _head(params, arch, h, cd, last_only=True)
+    cache["len"] = jnp.asarray(s, jnp.int32)
+    return logits, cache
+
+
 # ======================================================================
-# Decode caches
+# Decode
 # ======================================================================
 def make_cache(arch: ArchConfig, seq_len: int, batch: int,
                policy: DTypePolicy | None = None) -> Params:
     """Allocate (or shape-spec, under eval_shape) the decode cache."""
     policy = policy or DTypePolicy.standard()
     cd = policy.compute
-    hd = arch.resolved_head_dim
+    parts = layer_parts(arch)
     L, B = arch.n_layers, batch
+    kv = (arch.n_kv_heads, seq_len, arch.resolved_head_dim)
     cache: Params = {"len": jnp.zeros((), jnp.int32)}
-    if arch.family in ("dense", "moe", "vlm", "audio"):
-        if arch.attn_type == "mla":
-            cache["c_kv"] = jnp.zeros((L, B, seq_len, arch.kv_lora_rank), cd)
-            cache["k_rope"] = jnp.zeros((L, B, seq_len, arch.rope_head_dim), cd)
-        else:
-            cache["k"] = jnp.zeros((L, B, arch.n_kv_heads, seq_len, hd), cd)
-            cache["v"] = jnp.zeros((L, B, arch.n_kv_heads, seq_len, hd), cd)
-    if arch.is_encdec:
-        s_enc = max(seq_len // arch.cross_len_frac, 16)
-        cache["cross_k"] = jnp.zeros((L, B, arch.n_kv_heads, s_enc, hd), cd)
-        cache["cross_v"] = jnp.zeros((L, B, arch.n_kv_heads, s_enc, hd), cd)
-    if arch.family in ("ssm", "hybrid"):
+    if parts.mixer == "gqa":
+        cache["k"] = cache["v"] = jnp.zeros((L, B, *kv), cd)
+    elif parts.mixer == "mla":
+        cache["c_kv"] = jnp.zeros((L, B, seq_len, arch.kv_lora_rank), cd)
+        cache["k_rope"] = jnp.zeros((L, B, seq_len, arch.rope_head_dim), cd)
+    else:
         scfg = ssm_config(arch)
         cache["ssm_h"] = jnp.zeros(
             (L, B, scfg.n_heads, scfg.head_dim, scfg.d_state), jnp.float32)
         cache["ssm_conv"] = jnp.zeros(
             (L, B, scfg.conv_width - 1, scfg.conv_channels), cd)
-    if arch.family == "hybrid" and arch.shared_attn_every:
-        n_uses = -(-arch.n_layers // arch.shared_attn_every)
-        cache["shared_k"] = jnp.zeros(
-            (n_uses, B, arch.n_kv_heads, seq_len, hd), cd)
-        cache["shared_v"] = jnp.zeros(
-            (n_uses, B, arch.n_kv_heads, seq_len, hd), cd)
+    if parts.cross:
+        s_enc = max(seq_len // arch.cross_len_frac, 16)
+        cache["cross_k"] = cache["cross_v"] = jnp.zeros(
+            (L, B, arch.n_kv_heads, s_enc, arch.resolved_head_dim), cd)
+    if parts.shared_every:
+        n_uses = -(-arch.n_layers // parts.shared_every)
+        cache["shared_k"] = cache["shared_v"] = jnp.zeros((n_uses, B, *kv), cd)
     return cache
-
-
-# ======================================================================
-# Decode step
-# ======================================================================
-def _cross_attn_decode(bp: Params, arch: ArchConfig, x: jax.Array,
-                       ck: jax.Array, cv: jax.Array) -> jax.Array:
-    b = x.shape[0]
-    hd = arch.resolved_head_dim
-    q = (x @ bp["cross"]["wq"].astype(x.dtype)).reshape(
-        b, arch.n_heads, hd)
-    g = arch.n_heads // arch.n_kv_heads
-    qg = q.reshape(b, arch.n_kv_heads, g, hd)
-    s = jnp.einsum("bhgd,bhsd->bhgs", qg.astype(jnp.float32),
-                   ck.astype(jnp.float32)) / jnp.sqrt(hd)
-    w = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bhgs,bhsd->bhgd", w, cv.astype(jnp.float32))
-    o = o.reshape(b, 1, arch.n_heads * hd).astype(x.dtype)
-    return o @ bp["cross"]["wo"].astype(x.dtype)
-
-
-def _ffn(bp: Params, arch: ArchConfig, h: jax.Array,
-         matmul=cast_matmul) -> jax.Array:
-    """The feed-forward half of a decoder layer: norm, then the MLP (the
-    experts under ``moe``), added to the residual ``h``.  ``matmul`` is
-    the dense MLP's (``common.cast_matmul``)."""
-    with jax.named_scope("mlp"):
-        x = rms_norm(h, bp["ln2"]["scale"])
-        if arch.family == "moe":
-            return _residual(arch, h, moe_apply(bp["moe"], moe_config(arch), x))
-        return _residual(arch, h, mlp_apply(bp["mlp"], x, arch.act, matmul))
 
 
 def _split_stacks(blocks: Params, names: tuple[str, ...]
@@ -529,207 +510,74 @@ def decode_step(params: Params, arch: ArchConfig, cache: Params,
                 tokens: jax.Array, rt: RuntimeConfig | None = None,
                 policy: DTypePolicy | None = None
                 ) -> tuple[jax.Array, Params]:
-    """One decode step.  tokens: [B, 1] new token ids."""
+    """One decode step.  tokens: [B, 1] new token ids.  Each layer runs
+    ``_layer`` with its mixer's and cross attention's one-token forms,
+    then, where the model has one, the shared block."""
     rt = rt or RuntimeConfig()
     policy = policy or DTypePolicy.standard()
     cd = policy.compute
+    parts = layer_parts(arch)
     h = embed_tokens(params, arch, tokens, rt, cd)
     pos = cache["len"]
-    acfg = attn_config(arch)
+    acfg, xcfg = attn_config(arch), attn_config(arch, causal=False)
     emb0 = h
+    scfg = ssm_config(arch)
+    mla = parts.mixer == "mla"
+    keys = _CACHE_KEYS[parts.mixer]
+    # attention's and the dense MLP's matrices stay whole float32
+    # stacks, closed over by the scan (scanning them would slice
+    # each layer out), and are read by the layer's matmul
+    # (_stack_matmul); MLA's up-projections, which the absorbed
+    # attention applies per head, are sliced per layer and stay
+    # float32; the rest that is cast (MoE experts, cross attention)
+    # is cast once, under its scope, where XLA would otherwise hoist
+    # each layer's cast out of the scan with no name
+    mats, rest = _split_stacks(params["blocks"], ("attn", "mlp"))
+    per_head = {"attn": {k: mats["attn"].pop(k)
+                         for k in ("wk_b", "wv_b")}} if mla else {}
+    # GQA's and Mamba2's caches are scanned; MLA's stacks are closed
+    # over, and the latent attention reads its layer's slice itself, so
+    # that its scope (`latent`) holds all of its reading of the cache
+    stacks = tuple(cache[k] for k in keys)
+    cross = tuple(cache[k] for k in ("cross_k", "cross_v") if parts.cross)
+    xs = (_cast_blocks(rest, cd), per_head, jnp.arange(arch.n_layers),
+          () if mla else stacks, cross)
+    every = parts.shared_every
+    shared = {k: cache["shared_" + k] for k in ("k", "v") if every}
 
-    if arch.family in ("dense", "moe", "vlm") or arch.is_encdec:
-        mla = arch.attn_type == "mla"
-        keys = ("c_kv", "k_rope") if mla else ("k", "v")
-        # attention's and the dense MLP's matrices stay whole float32
-        # stacks, closed over by the scan (scanning them would slice
-        # each layer out), and are read by the layer's matmul
-        # (_stack_matmul); MLA's up-projections, which the absorbed
-        # attention applies per head, are sliced per layer and stay
-        # float32; the rest that is cast (MoE experts, cross attention)
-        # is cast once, under its scope, where XLA would otherwise hoist
-        # each layer's cast out of the scan with no name
-        mats, rest = _split_stacks(params["blocks"], ("attn", "mlp"))
-        per_head = {k: mats["attn"].pop(k) for k in ("wk_b", "wv_b") if mla}
-        # GQA's cache is scanned; MLA's stacks are closed over, and the
-        # latent attention reads its layer's slice itself, so that its
-        # scope (`latent`) holds all of its reading of the cache
-        stacks = (cache[keys[0]], cache[keys[1]])
-        xs = (_cast_blocks(rest, cd), per_head, jnp.arange(arch.n_layers),
-              () if mla else stacks)
-        if arch.is_encdec:
-            xs += (cache["cross_k"], cache["cross_v"])
-
-        def layer(carry, x):
-            hh = carry
-            bp, ph, idx, kv = x[:4]
-            bp = {**bp, **{k: {**bp[k], **m} for k, m in mats.items()}}
-            bp["attn"] = {**bp["attn"], **ph}
-            matmul = _stack_matmul(idx)
-            with jax.named_scope("attn"):
-                xn = rms_norm(hh, bp["ln"]["scale"])
-                if mla:
-                    o, kv = mla_decode(bp["attn"], acfg, xn, stacks, pos,
-                                       matmul, layer=idx)
-                else:
-                    o, kv = gqa_decode(bp["attn"], acfg, xn, kv, pos, matmul)
-                hh = _residual(arch, hh, o)
-                if arch.is_encdec:
-                    xc = rms_norm(hh, bp["ln_cross"]["scale"])
-                    hh = _residual(arch, hh, _cross_attn_decode(
-                        bp, arch, xc[:, 0], *x[4:]))
-            return _ffn(bp, arch, hh, matmul), kv
-
-        with jax.named_scope("layers"):
-            h, (c0, c1) = jax.lax.scan(layer, h, xs)
-        cache = {**cache, keys[0]: c0, keys[1]: c1}
-    else:  # ssm / hybrid
-        scfg = ssm_config(arch)
-        every = arch.shared_attn_every
-        sk = cache.get("shared_k")
-        sv = cache.get("shared_v")
-
-        def layer(carry, x):
-            hh, sk, sv = carry
-            bp, hc, cc, idx = x
-            with jax.named_scope("mlp"):
-                xn = rms_norm(hh, bp["ln"]["scale"])
-                o, (hc, cc) = mamba2_decode(bp["mamba"], scfg, xn, (hc, cc))
-                hh = _residual(arch, hh, o)
-
-            if arch.family == "hybrid" and every:
+    def layer(carry, x):
+        hh, sh = carry
+        bp, ph, idx, kv, cross_kv = x
+        for group, m in (*mats.items(), *ph.items()):
+            bp = {**bp, group: {**bp[group], **m}}
+        matmul = _stack_matmul(idx)
+        mix = {
+            "gqa": lambda p, xn: gqa_decode(p, acfg, xn, kv, pos, matmul),
+            "mla": lambda p, xn: mla_decode(p, acfg, xn, stacks, pos, matmul,
+                                            layer=idx),
+            "mamba": lambda p, xn: mamba2_decode(p, scfg, xn, kv),
+        }[parts.mixer]
+        hh, _, entries = _layer(
+            bp, arch, parts, hh, rt, mix,
+            lambda p, xn: cross_decode(p, xcfg, xn, cross_kv), matmul)
+        if every:
+            def use_shared(args):
+                hh, sh = args
                 u = idx // every
+                hh, kv = _shared_block(
+                    params["shared"], arch, hh, emb0, rt,
+                    lambda p, xn: gqa_decode(p, acfg, xn,
+                                             (sh["k"][u], sh["v"][u]), pos))
+                return hh, {k: jax.lax.dynamic_update_index_in_dim(
+                    sh[k], kv[k], u, 0) for k in sh}
 
-                def do_shared(args):
-                    hh, sk, sv = args
-                    sp = params["shared"]
-                    with jax.named_scope("attn"):
-                        z = jnp.concatenate([hh, emb0.astype(hh.dtype)], -1)
-                        z = z @ sp["w_cat"].astype(hh.dtype)
-                        xn2 = rms_norm(z, sp["ln"]["scale"])
-                        ku, vu = sk[u], sv[u]
-                        o2, (ku, vu) = gqa_decode(sp["attn"], acfg, xn2,
-                                                  (ku, vu), pos)
-                        z = z + o2
-                        sk = jax.lax.dynamic_update_index_in_dim(sk, ku, u, 0)
-                        sv = jax.lax.dynamic_update_index_in_dim(sv, vu, u, 0)
-                    with jax.named_scope("mlp"):
-                        x2 = rms_norm(z, sp["ln2"]["scale"])
-                        z = z + mlp_apply(sp["mlp"], x2, arch.act)
-                    return hh + z, sk, sv
+            hh, sh = jax.lax.cond(idx % every == 0, use_shared,
+                                  lambda a: a, (hh, sh))
+        return (hh, sh), {k: entries[k] for k in keys}
 
-                hh, sk, sv = jax.lax.cond(
-                    (idx % every) == 0, do_shared, lambda a: a, (hh, sk, sv))
-            return (hh, sk, sv), (hc, cc)
-
-        if sk is None:
-            sk = jnp.zeros((1,), jnp.float32)
-            sv = jnp.zeros((1,), jnp.float32)
-        with jax.named_scope("layers"):
-            (h, sk, sv), (hc, cc) = jax.lax.scan(
-                layer, (h, sk, sv),
-                (params["blocks"], cache["ssm_h"], cache["ssm_conv"],
-                 jnp.arange(arch.n_layers)))
-        cache = {**cache, "ssm_h": hc, "ssm_conv": cc}
-        if arch.family == "hybrid" and every:
-            cache = {**cache, "shared_k": sk, "shared_v": sv}
-
+    with jax.named_scope("layers"):
+        (h, shared), new = jax.lax.scan(layer, (h, shared), xs)
+    cache = {**cache, **new, **{"shared_" + k: c for k, c in shared.items()}}
     logits = _head(params, arch, h, cd)
     cache = {**cache, "len": cache["len"] + 1}
     return constrain(logits, "logits", rt), cache
-
-
-def prefill(params: Params, arch: ArchConfig, batch: dict[str, jax.Array],
-            cache_len: int, rt: RuntimeConfig | None = None,
-            policy: DTypePolicy | None = None) -> tuple[jax.Array, Params]:
-    """Run the full-sequence forward and populate a decode cache of
-    capacity ``cache_len`` (>= prompt length)."""
-    rt = rt or RuntimeConfig()
-    policy = policy or DTypePolicy.standard()
-    cd = policy.compute
-    tokens = batch["tokens"]
-    b, s = tokens.shape
-    cache = make_cache(arch, cache_len, b, policy)
-    h = embed_tokens(params, arch, tokens, rt, cd)
-    acfg = attn_config(arch)
-    # the attention families use every layer weight in the compute dtype
-    # (the SSM mixer keeps some in f32): cast the stack once, as in decode
-    blocks = params["blocks"]
-    if arch.family in ("dense", "moe", "vlm") or arch.is_encdec:
-        blocks = _cast_blocks(blocks, cd)
-
-    if arch.is_encdec:
-        # encoder once; decoder prefill caches self-KV + per-layer cross-KV
-        enc_out = _encoder_forward(params, arch,
-                                   batch["frames"].astype(cd), rt)
-        hd = arch.resolved_head_dim
-        xcfg = attn_config(arch, causal=False)
-
-        def layer(hh, bp):
-            with jax.named_scope("attn"):
-                xn = rms_norm(hh, bp["ln"]["scale"])
-                o, (kc, vc) = gqa_prefill(bp["attn"], acfg, xn)
-                hh = _residual(arch, hh, o)
-                xc = rms_norm(hh, bp["ln_cross"]["scale"])
-                be, se, _ = enc_out.shape
-                q = (xc @ bp["cross"]["wq"].astype(cd)).reshape(
-                    be, -1, xcfg.n_heads, hd)
-                xk = (enc_out.astype(cd) @ bp["cross"]["wk"].astype(cd)
-                      ).reshape(be, se, xcfg.n_kv_heads, hd)
-                xv = (enc_out.astype(cd) @ bp["cross"]["wv"].astype(cd)
-                      ).reshape(be, se, xcfg.n_kv_heads, hd)
-                o2 = flash_attention(jnp.swapaxes(q, 1, 2),
-                                     jnp.swapaxes(xk, 1, 2),
-                                     jnp.swapaxes(xv, 1, 2), causal=False)
-                o2 = o2.swapaxes(1, 2).reshape(be, -1, xcfg.n_heads * hd)
-                hh = _residual(arch, hh, o2 @ bp["cross"]["wo"].astype(cd))
-            hh = _ffn(bp, arch, hh)
-            return constrain(hh, "hidden", rt), (
-                kc, vc, jnp.swapaxes(xk, 1, 2), jnp.swapaxes(xv, 1, 2))
-
-        with jax.named_scope("layers"):
-            h, (kc, vc, xk, xv) = jax.lax.scan(layer, h, blocks)
-            pad = ((0, 0), (0, 0), (0, 0), (0, cache_len - s), (0, 0))
-            cache["k"] = jnp.pad(kc.astype(cd), pad)
-            cache["v"] = jnp.pad(vc.astype(cd), pad)
-            s_enc = cache["cross_k"].shape[3]
-            cache["cross_k"] = xk[:, :, :, :s_enc].astype(cd)
-            cache["cross_v"] = xv[:, :, :, :s_enc].astype(cd)
-    elif arch.family in ("dense", "moe", "vlm"):
-        mla = arch.attn_type == "mla"
-        attend = mla_prefill if mla else gqa_prefill
-        keys = ("c_kv", "k_rope") if mla else ("k", "v")
-
-        def layer(hh, bp):
-            with jax.named_scope("attn"):
-                xn = rms_norm(hh, bp["ln"]["scale"])
-                o, (c0, c1) = attend(bp["attn"], acfg, xn)
-                hh = _residual(arch, hh, o)
-            return constrain(_ffn(bp, arch, hh), "hidden", rt), (c0, c1)
-
-        with jax.named_scope("layers"):
-            h, (c0, c1) = jax.lax.scan(layer, h, blocks)
-            for key, c in zip(keys, (c0, c1)):
-                # [L, B, (heads,) S, D]: pad the positions to cache_len
-                pad = [(0, 0)] * c.ndim
-                pad[-2] = (0, cache_len - s)
-                cache[key] = jnp.pad(c.astype(cd), pad)
-    elif arch.family in ("ssm", "hybrid"):
-        def layer(hh, bp):
-            with jax.named_scope("mlp"):
-                xn = rms_norm(hh, bp["ln"]["scale"])
-                o, (hf, conv_tail) = mamba2_apply(
-                    bp["mamba"], ssm_config(arch), xn, return_state=True)
-                hh = _residual(arch, hh, o)
-            return constrain(hh, "hidden", rt), (hf, conv_tail)
-
-        # Note: prefill for hybrid ignores the shared attention block's
-        # cache population here for brevity of the driver; serving tests
-        # exercise decode_step from a zero cache instead.
-        with jax.named_scope("layers"):
-            h, (hf, conv_tail) = jax.lax.scan(layer, h, blocks)
-        cache["ssm_h"] = hf
-        cache["ssm_conv"] = conv_tail.astype(cd)
-    logits = _head(params, arch, h, cd, last_only=True)
-    cache = {**cache, "len": jnp.asarray(s, jnp.int32)}
-    return logits, cache

@@ -84,6 +84,19 @@ def test_serve_driver_ssm():
     assert out["generated"].shape == (2, 4)
 
 
+@pytest.mark.parametrize("name", ["zamba2-2.7b", "seamless-m4t-medium",
+                                  "internvl2-1b"])
+def test_serve_driver_prefills_every_family(name):
+    """The hybrid, the encoder-decoder (given frames) and the VLM (given
+    patches) are prefilled like the rest, and decode from the cache that
+    their prefill filled."""
+    from repro.launch.serve import main
+    out = main(["--arch", name, "--preset", "tiny", "--batch", "2",
+                "--prompt-len", "16", "--gen", "4"])
+    assert out["prefill_logits"].shape[:2] == (2, 1)
+    assert out["generated"].shape == (2, 4)
+
+
 def test_compile_cache_dir_env_then_checkout(monkeypatch, tmp_path):
     from repro.launch.compile_cache import compile_cache_dir
 

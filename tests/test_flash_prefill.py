@@ -20,8 +20,8 @@ from repro.configs.base import RuntimeConfig
 from repro.kernels.ref import causal_attention_ref
 from repro.launch.sharding import activation_sharding
 from repro.models import attention, init_model, loss_fn
-from repro.models.attention import (AttnConfig, gqa_init, gqa_prefill,
-                                    mla_init, mla_prefill)
+from repro.models.attention import (AttnConfig, gqa_apply, gqa_init,
+                                    mla_apply, mla_init)
 
 # the package exports the function under the module's name
 kernel_module = sys.modules["repro.kernels.flash_prefill"]
@@ -31,7 +31,7 @@ BF16 = jnp.bfloat16
 
 
 def _chip_scan(monkeypatch, q, k, v):
-    """``_flash_block_scan`` with its einsums at the TPU's default
+    """``flash_attention`` with its einsums at the TPU's default
     precision: each float32 operand rounded to bfloat16, float32 out."""
     chip = types.SimpleNamespace(**{n: getattr(jnp, n) for n in dir(jnp)
                                     if not n.startswith("__")})
@@ -40,7 +40,7 @@ def _chip_scan(monkeypatch, q, k, v):
         preferred_element_type=jnp.float32)
     with monkeypatch.context() as m:
         m.setattr(attention, "jnp", chip)
-        return attention._flash_block_scan(q, k, v, True, 0, 1024)
+        return attention.flash_attention(q, k, v, True, 0, 1024)
 
 
 def _qkv(b, hq, hkv, s, d, seed=0):
@@ -102,14 +102,14 @@ def test_blocks_and_the_pairs_the_grid_visits():
 def _gqa():
     cfg = AttnConfig(d_model=64, n_heads=4, n_kv_heads=2, head_dim=32,
                      qk_norm=True)
-    return cfg, gqa_init(jax.random.PRNGKey(1), cfg), gqa_prefill
+    return cfg, gqa_init(jax.random.PRNGKey(1), cfg), gqa_apply
 
 
 def _mla():
     cfg = AttnConfig(d_model=64, n_heads=4, n_kv_heads=4, head_dim=16,
                      attn_type="mla", q_lora_rank=32, kv_lora_rank=16,
                      rope_head_dim=8)
-    return cfg, mla_init(jax.random.PRNGKey(1), cfg), mla_prefill
+    return cfg, mla_init(jax.random.PRNGKey(1), cfg), mla_apply
 
 
 def _one_device_mesh():
@@ -119,21 +119,24 @@ def _one_device_mesh():
 
 @pytest.mark.parametrize("build", [_gqa, _mla], ids=["gqa", "mla"])
 def test_prefill_takes_the_kernel_unless_partitioned(monkeypatch, build):
-    """Unpartitioned, ``gqa_prefill`` and ``mla_prefill`` run the kernel
-    (a ``pallas_call`` named ``flash_prefill`` in interpret mode); under
-    a launcher's activation sharder they run the scan, to the same
-    output within bfloat16 rounding."""
+    """Unpartitioned, ``gqa_apply`` and ``mla_apply`` given
+    ``prefill_attention`` run the kernel (a ``pallas_call`` named
+    ``flash_prefill`` in interpret mode); under a launcher's activation
+    sharder they run the scan, to the same output within bfloat16
+    rounding."""
     monkeypatch.setenv("REPRO_KERNEL_MODE", "interpret")
     cfg, params, prefill_fn = build()
     x = jax.random.normal(jax.random.PRNGKey(2), (2, 24, 64)).astype(BF16)
     params = jax.tree.map(lambda w: w.astype(BF16), params)
-    run = lambda p, xx: prefill_fn(p, cfg, xx)        # noqa: E731
+    run = lambda p, xx: prefill_fn(                   # noqa: E731
+        p, cfg, xx, attention.prefill_attention)
     kernel_jaxpr = str(jax.make_jaxpr(run)(params, x))
     assert "pallas_call" in kernel_jaxpr and "flash_prefill" in kernel_jaxpr
     out, cache = run(params, x)
     with activation_sharding(_one_device_mesh(), ("data",)):
         # a new function: JAX would reuse the trace made outside
-        scan_run = lambda p, xx: prefill_fn(p, cfg, xx)   # noqa: E731
+        scan_run = lambda p, xx: prefill_fn(          # noqa: E731
+            p, cfg, xx, attention.prefill_attention)
         assert "pallas_call" not in str(jax.make_jaxpr(scan_run)(params, x))
         out_scan, cache_scan = scan_run(params, x)
     np.testing.assert_allclose(np.asarray(out, np.float32),

@@ -13,7 +13,7 @@ from repro.models import (decode_step, forward, init_model, loss_fn,
                           make_cache, prefill)
 from repro.models.attention import (AttnConfig, _mla_qkv_full,
                                     flash_attention, gqa_apply, gqa_init,
-                                    mla_decode, mla_init, mla_prefill)
+                                    mla_apply, mla_decode, mla_init)
 from repro.models.ssm import ssd_chunked, ssd_reference
 
 RT = RuntimeConfig(remat="none")
@@ -121,8 +121,8 @@ def test_gqa_kv_replication_equivalence():
     x = jnp.asarray(np.random.default_rng(1).standard_normal((2, 16, 32)),
                     jnp.float32)
     np.testing.assert_allclose(
-        np.asarray(gqa_apply(params, cfg1, x)),
-        np.asarray(gqa_apply(params, cfg2, x)), atol=1e-5)
+        np.asarray(gqa_apply(params, cfg1, x)[0]),
+        np.asarray(gqa_apply(params, cfg2, x)[0]), atol=1e-5)
 
 
 def test_mla_absorb_equivalence():
@@ -136,7 +136,7 @@ def test_mla_absorb_equivalence():
     params = mla_init(KEY, cfg)
     rng = np.random.default_rng(2)
     x_ctx = jnp.asarray(rng.standard_normal((2, 6, 32)), jnp.float32)
-    _, (c_kv, k_rope) = mla_prefill(params, cfg, x_ctx)
+    _, (c_kv, k_rope) = mla_apply(params, cfg, x_ctx)
     pad = 10 - 6
     cache = (jnp.pad(c_kv, ((0, 0), (0, pad), (0, 0))),
              jnp.pad(k_rope, ((0, 0), (0, pad), (0, 0))))
@@ -171,20 +171,35 @@ def test_ssd_chunked_matches_reference():
     np.testing.assert_allclose(np.asarray(h1), np.asarray(h2), atol=1e-4)
 
 
-@pytest.mark.parametrize("name", ["qwen3-1.7b", "minicpm3-4b", "mamba2-130m"])
+@pytest.mark.parametrize("name", ARCH_NAMES)
 def test_prefill_decode_matches_forward(name):
-    """prefill(ctx) then decode(tok) must reproduce forward(ctx+tok)."""
-    arch = tiny_variant(get_arch(name))
+    """prefill(ctx) then decode(tok) must reproduce forward(ctx+tok), in
+    every architecture: the VLM's image prefix is in both batches and in
+    the cache, the encoder-decoder's frames fill its cross cache, and
+    the experts have room for every token (none is dropped)."""
+    arch = tiny_variant(get_arch(name), moe_capacity_factor=4.0)
     params = init_model(KEY, arch)
     rng = np.random.default_rng(4)
     toks = jnp.asarray(rng.integers(1, arch.vocab - 1, (2, 12)), jnp.int32)
+    extra, prefix = {}, 0
+    if arch.family == "vlm":
+        prefix = arch.n_patches
+        extra["patches"] = jnp.asarray(
+            rng.standard_normal((2, prefix, arch.vit_dim)), jnp.float32)
+    if arch.is_encdec:
+        # as long as make_cache's cross cache for 16 positions
+        extra["frames"] = jnp.asarray(
+            rng.standard_normal((2, 16, arch.d_model)), jnp.float32)
     # full forward over all 12 tokens
-    logits_full, _ = forward(params, arch, {"tokens": toks}, RT)
+    logits_full, _ = forward(params, arch, {"tokens": toks, **extra}, RT)
     # prefill on 11, decode token 12
-    logits_p, cache = prefill(params, arch, {"tokens": toks[:, :11]}, 16, RT)
+    _, cache = prefill(params, arch, {"tokens": toks[:, :11], **extra},
+                       prefix + 16, RT)
+    assert jax.tree.structure(cache) == jax.tree.structure(
+        make_cache(arch, prefix + 16, 2))
     logits_d, _ = decode_step(params, arch, cache, toks[:, 11:12], RT)
     np.testing.assert_allclose(np.asarray(logits_d[:, 0]),
-                               np.asarray(logits_full[:, 11]),
+                               np.asarray(logits_full[:, prefix + 11]),
                                atol=2e-2, rtol=2e-2)
 
 
